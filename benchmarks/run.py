@@ -21,6 +21,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = ("roofline", "nero", "precision", "napel", "leaper", "sibyl",
           "serve", "traffic")
 
@@ -31,6 +33,7 @@ def main() -> None:
                     help="comma-separated subset of: " + ",".join(SUITES))
     args, _ = ap.parse_known_args()
     picked = args.only.split(",") if args.only else list(SUITES)
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -94,14 +94,21 @@ def _freeze(kw: dict) -> tuple:
     return tuple(sorted(kw.items()))
 
 
+def default_interpret() -> bool:
+    """Pallas kernels compile on a TPU and are interpreted elsewhere."""
+    return jax.default_backend() != "tpu"
+
+
 def run(name: str, *args, backend: str = "pallas", tile=None,
         interpret: bool | None = None, **kwargs):
     """Single entry point over every registered kernel.
 
-    backend="pallas" runs the Pallas kernel (interpret, default True,
-    executes the kernel body on CPU for validation); "ref" runs the jnp
-    oracle; "auto" runs Pallas with tile=None resolved to the knee point
-    of the spec's cost model over its tune_space (repro.core.autotune).
+    backend="pallas" runs the Pallas kernel; "ref" runs the jnp oracle;
+    "auto" runs Pallas with tile=None resolved to the knee point of the
+    spec's cost model over its tune_space (repro.core.autotune).
+    interpret=None follows the platform: compiled by Mosaic on a TPU,
+    interpreted (the kernel body executed op by op) everywhere else. A
+    kernel that does not compile on the TPU raises; nothing falls back.
     tile=/interpret= are Pallas-only: passing either with backend="ref"
     raises, so a typoed benchmark call can't silently measure the oracle.
     """
@@ -116,7 +123,7 @@ def run(name: str, *args, backend: str = "pallas", tile=None,
                 f"no tile parameters; drop them or use backend='pallas'")
         return _jitted(spec.name, "ref", _freeze(kwargs))(*args)
     if interpret is None:
-        interpret = True
+        interpret = default_interpret()
     if tile is None:
         tile = resolve_tile(spec, args) if backend == "auto" else {}
     tile = dict(tile)

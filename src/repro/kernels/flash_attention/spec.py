@@ -10,7 +10,7 @@ import numpy as np
 from repro.core.autotune import (GRID_STEP_OVERHEAD_S, HBM_BW, LANE,
                                  PEAK_FLOPS)
 from repro.kernels import registry
-from repro.kernels.api import KernelCase, KernelSpec
+from repro.kernels.api import KernelCase, KernelSpec, default_interpret
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.flash_attention import flash_attention_pallas
 
@@ -20,7 +20,9 @@ BENCH_SHAPE = {"b": 8, "sq": 2048, "skv": 2048, "hq": 32, "hkv": 8, "d": 128}
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, window=0, block_q=128, block_k=128,
-                    interpret=True):
+                    interpret=None):
+    if interpret is None:
+        interpret = default_interpret()
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   block_q=block_q, block_k=block_k,
                                   interpret=interpret)
@@ -42,7 +44,7 @@ flash_attention.defvjp(_fwd, _bwd)
 
 
 def _pallas_entry(q, k, v, *, causal=True, window=0, block_q=128,
-                  block_k=128, interpret=True):
+                  block_k=128, interpret=None):
     """Keyword-style wrapper so the registry dispatch (api.run) reaches the
     differentiable custom-vjp entry with tile params as kwargs."""
     return flash_attention(q, k, v, causal, window, block_q, block_k,

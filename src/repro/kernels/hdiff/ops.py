@@ -9,7 +9,7 @@ from repro.kernels import api
 
 
 def hdiff(src, *, use_kernel: bool = True, block_z: int = 1,
-          interpret: bool = True):
+          interpret: bool | None = None):
     """Horizontal diffusion over a (nz, ny, nx) grid."""
     if not use_kernel:
         return api.run("hdiff", src, backend="ref")

@@ -109,7 +109,8 @@ def _paged_kernel(*args, ppb: int, t: int, scale: float, stacked: bool,
 
 def paged_attention_pallas(q, k_pages, v_pages, k_quant, v_quant, k_scale,
                            v_scale, page_table, lengths, layer=None, *,
-                           pages_per_block: int = 4, head_block: int = 1,
+                           pages_per_block: int = 4,
+                           head_block: int | None = None,
                            softmax_scale=None, interpret: bool = False):
     """q: (b, hq, d) single decode token, or (b, k, hq, d) for k
     consecutive causal positions per sequence (row j valid up to
@@ -118,7 +119,9 @@ def paged_attention_pallas(q, k_pages, v_pages, k_quant, v_quant, k_scale,
     (L, P, T, hkv, d) with ``layer`` a scalar int32 (may be traced) naming
     the layer to attend; {k,v}_scale: (P, T, hkv) or (L, P, T, hkv);
     page_table: (b, slots) int32; lengths: (b,) int32 (>= 1 per
-    sequence, row 0's length). Returns q's shape."""
+    sequence, row 0's length). ``head_block=None`` reduces all kv heads
+    per grid step, the one block every TPU accepts (see
+    `spec.head_block_ok`). Returns q's shape."""
     stacked = k_pages.ndim == 5
     if stacked and layer is None:
         raise ValueError("layer-stacked pools need a layer index")
@@ -135,7 +138,7 @@ def paged_attention_pallas(q, k_pages, v_pages, k_quant, v_quant, k_scale,
     g = hq // hkv
     kg = kq * g
     ppb = min(pages_per_block, slots)
-    hb = min(head_block, hkv)
+    hb = hkv if head_block is None else min(head_block, hkv)
     assert slots % ppb == 0 and hkv % hb == 0, (slots, ppb, hkv, hb)
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(d)
 

@@ -26,8 +26,22 @@ BENCH_SHAPE = {"b": 16, "pages": 512, "page_tokens": 64, "slots": 32,
                "hq": 32, "hkv": 8, "d": 128, "k": 1}
 
 
+HEAD_BLOCKS = (1, 2, 4, 8, 16, 32, 64, 128)
+
+
+def head_block_ok(hkv: int, hb: int) -> bool:
+    """Mosaic's block rule: the last two dims of every block are multiples
+    of (8, 128) or equal the array's. The pool blocks end in (hb, d) and
+    the scale blocks in (t, hb), so a head block must span all ``hkv``
+    heads or be a multiple of 128 (which also meets the wider second-minor
+    tiling of bf16 and int8 pools)."""
+    return hkv % hb == 0 and (hb == hkv or hb % LANE == 0)
+
+
 def paged_cost(grid_shape, tile: dict, dtype_bytes: int) -> tuple | None:
-    """tile = {"pages_per_block": ppb, "head_block": hb}. Decode is
+    """tile = {"pages_per_block": ppb, "head_block": hb}; None for a tile
+    that does not divide the grid or that the TPU refuses
+    (`head_block_ok`). Decode is
     traffic-bound: the whole paged KV streams once per kv head (fast float
     + int8 + scale are all fetched; tier saving shows up as the int8 pool
     being the only populated one for slow pages), while q/out are k
@@ -38,7 +52,7 @@ def paged_cost(grid_shape, tile: dict, dtype_bytes: int) -> tuple | None:
     face of "more compute per byte moved"."""
     b, pages, t, slots, hq, hkv, d, k = grid_shape
     ppb, hb = tile["pages_per_block"], tile["head_block"]
-    if slots % ppb or hkv % hb:
+    if slots % ppb or not head_block_ok(hkv, hb):
         return None
     g = hq // hkv
     # bytes of one (page, head-block) row set: float pool + int8 + scale
@@ -149,7 +163,7 @@ SPEC = registry.register(KernelSpec(
                "k_scale", "v_scale", "page_table", "lengths"),
     shape_keys=("b", "pages", "page_tokens", "slots", "hq", "hkv", "d", "k"),
     tune_space={"pages_per_block": (1, 2, 4, 8),
-                "head_block": (1, 2, 4)},
+                "head_block": HEAD_BLOCKS},
     cost_fn=paged_cost,
     example_inputs=example_inputs,
     # 2 matmuls x 2 flops over every (q row, q head, kv position) pair

@@ -5,7 +5,7 @@ from repro.kernels import api
 
 
 def lru_scan(a, b, *, use_kernel: bool = True, chunk: int = 256,
-             interpret: bool = True):
+             interpret: bool | None = None):
     if not use_kernel:
         return api.run("rglru_scan", a, b, backend="ref")
     return api.run("rglru_scan", a, b, backend="pallas",

@@ -1,15 +1,9 @@
-"""Production meshes + jax version-compat constructors.
+"""Production meshes.
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state. The dry-run launcher sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before* any jax
 import; everything else sees the real device count.
-
-The compat helpers paper over two jax API breaks:
- - ``jax.sharding.AxisType`` (and ``jax.make_mesh(..., axis_types=)``) does
-   not exist on 0.4.x — fall back to plain ``jax.make_mesh``.
- - ``AbstractMesh`` took a single tuple-of-(name, size) pairs on 0.4.x but
-   ``(axis_sizes, axis_names)`` on newer releases.
 """
 from __future__ import annotations
 
@@ -17,21 +11,15 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` that requests Auto axis types only where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """`jax.make_mesh` with Auto axis types on every axis."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
-    """Deviceless `AbstractMesh` across the 0.4.x -> 0.5+ signature change."""
+    """Deviceless `AbstractMesh` of these axis sizes and names."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:                       # 0.4.x: tuple of (name, size)
-        return AbstractMesh(tuple(zip(tuple(axes), tuple(shape))))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

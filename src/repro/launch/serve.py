@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 from repro.configs import get_config, list_archs, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.kvcache import PagedKVPool
 
@@ -123,6 +124,7 @@ def main():
                          "engine construction, saved after serving, so "
                          "restarts skip re-tuning")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.external_embed:

@@ -1010,23 +1010,22 @@ def _wrap_step(step, model, plan, *, control_spec, out_spec, layout=None):
     """jit the step; under a mesh plan, shard_map it first: params by the
     serve partition rules, pool + recurrent-store arrays by the kernel's
     head-sharded calling convention, decode rows over "data".
-    check_rep=False because the body's donated scatters + psum seams are
+    check_vma=False because the body's donated scatters + psum seams are
     not replication-safe to infer; correctness is asserted by the
     sharded-vs-single-device equivalence tests."""
     if plan is None:
         return jax.jit(step, donate_argnums=(1,))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     cfg = model.cfg
     rep_heads = cfg.num_kv_heads > 0 and cfg.num_kv_heads % plan.tp != 0
     arr_specs = plan.pool_specs(replicate_heads=rep_heads)
     if layout is not None:
         arr_specs = arr_specs + rec_array_specs(layout, plan)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         step, mesh=plan.mesh,
         in_specs=(plan.param_specs(model), arr_specs) + control_spec
         + (P(),),
-        out_specs=(out_spec, arr_specs), check_rep=False)
+        out_specs=(out_spec, arr_specs), check_vma=False)
     return jax.jit(mapped, donate_argnums=(1,))
 
 

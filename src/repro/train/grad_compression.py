@@ -61,17 +61,6 @@ def compressed_psum(x, axis_name: str):
     return jnp.tensordot(ss, qs.astype(jnp.float32), axes=((0,), (0,)))
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` compat: top-level alias (and its `check_vma` kwarg)
-    only exist on newer jax; 0.4.x has the experimental spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def data_parallel_mean_compressed(grads, mesh, axis: str = "data"):
     """Compressed DP-mean over one mesh axis via shard_map (demo/benchmark
     path; the production train_step lets XLA emit the fused reduce)."""
@@ -82,4 +71,5 @@ def data_parallel_mean_compressed(grads, mesh, axis: str = "data"):
             lambda t: compressed_psum(t, axis) / mesh.shape[axis], g)
 
     spec = jax.tree.map(lambda _: P(), grads)
-    return _shard_map(f, mesh, (spec,), spec)(grads)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(grads)

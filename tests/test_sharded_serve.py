@@ -222,7 +222,6 @@ def test_kernel_head_sharded_shard_map_matches_ref():
     ids per data shard, kv/q heads split over the model axis, and the
     sharded result equals the global reference with global page ids —
     i.e. no shard ever needed a remote page."""
-    from jax.experimental.shard_map import shard_map
     from repro.kernels.paged_attention import ref
     from repro.kernels.paged_attention.spec import head_sharded_specs
     from repro.launch.mesh import make_serve_mesh
@@ -256,10 +255,10 @@ def test_kernel_head_sharded_shard_map_matches_ref():
     specs = head_sharded_specs(layer_stacked=False)
     args = ("q", "k_pages", "v_pages", "k_quant", "v_quant",
             "k_scale", "v_scale", "page_table", "lengths")
-    sharded = jax.jit(shard_map(
+    sharded = jax.jit(jax.shard_map(
         ref.paged_attention, mesh=mesh,
         in_specs=tuple(specs[a] for a in args),
-        out_specs=specs["out"], check_rep=False))
+        out_specs=specs["out"], check_vma=False))
     out = sharded(q, kf, vf, kq, vq, ks, vs, table_local, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-6, atol=2e-6)
