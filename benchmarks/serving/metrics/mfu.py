@@ -1,0 +1,2 @@
+"""Fused step: model FLOPs of the tokens fed in the traced window over chips x peak bf16 FLOP/s (%); open-loop cells."""
+from serving.readers import mfu as read  # noqa: F401
