@@ -209,6 +209,7 @@ def paged_attention_pallas(q, k_pages, v_pages, k_quant, v_quant, k_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, kg, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*scalars, qg, *operands[1:])
     if multi:
         return out.reshape(b, hkv, kq, g, d).transpose(0, 2, 1, 3, 4) \

@@ -42,6 +42,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.kernels import api
 from repro.models import Model
+from repro.serve import tracing
 from repro.serve.kvcache import PagedKVPool, pad_caches
 from repro.serve.paged_state import StateLayout
 from repro.serve.paged_decode import (MODES, PagedKVState, build_fused_step,
@@ -726,12 +727,8 @@ class ServeSession:
             self.capacity, batch_hint=n_rows,
             tail_slots=2 if (k > 1 or self.chunked) else 1)
         # prefix-cache hit accounting (pages adopted / adoptable pages)
-        # and per-step wall time of decode work that shared a step with a
-        # prefill chunk — bench_traffic derives hit rate and decode-p99-
-        # during-admission from these
         self.pages_adopted_total = 0
         self.pages_needed_total = 0
-        self.prefill_step_decode_ms: list[float] = []
         self._rows: list[Optional[_Active]] = [None] * n_rows
         self._recs: dict[int, _SessionRec] = {}
         self._key = jax.random.PRNGKey(seed)
@@ -1220,170 +1217,183 @@ class ServeSession:
         chunk rows stream one prompt page each through the verify graph
         while every decode row keeps decoding in the same fused launch —
         long prompts admit page-by-page without stalling in-flight
-        requests."""
-        events: list[StreamEvent] = list(self._pending_events)
-        self._pending_events.clear()
-        self._admit(events)
-        rows = self._rows
-        if all(a is None for a in rows):
-            if not self.sched.done:   # unreachable: submit() rejects instead
-                raise RuntimeError("scheduler stalled with waiting "
-                                   "requests and no active rows")
-            return events
-        eng, pool, state = self.engine, self.pool, self.state
-        t = pool.page_tokens
-        chunk_rows: dict[int, tuple[int, bool]] = {}   # row -> (m, final)
-        wide = any(a is not None and a.prefilling for a in rows)
-        spec = self.spec_k > 1 or wide
-        n_rows = len(rows)      # mesh plan: max_active padded to dp blocks
-        if not spec:       # the spec branch derives these from srows
-            pos = np.zeros(n_rows, np.int32)
-            seq_ids = [-1] * n_rows
-            for i, act in enumerate(rows):
-                if act is None:
-                    continue
-                pos[i] = act.pos
-                seq_ids[i] = act.seq
-        t0 = time.perf_counter()
-        hits0 = (pool.stats["fast_hits"], pool.stats["slow_hits"])
-        g0 = state.gather_s
-        if spec:
-            # speculative verify step: k rows per live request, mixed
-            # freely with eff_k=1 (plain) rows and prefill chunk rows;
-            # tokens ride in the control block, so no device-token
-            # feedback is needed
-            k = max(self.spec_k, t) if wide else self.spec_k
-            step_fn = eng._fused_step_fn(state.slots, self.greedy,
-                                         self.temperature, k=k) \
-                if wide else self._step_fn
-            budget = self.prefill_budget
-            srows: list[Optional[dict]] = []
-            for act in rows:
-                if act is None:
-                    srows.append(None)
-                    continue
-                if act.prefilling:
-                    if budget <= 0:
-                        srows.append(None)   # over budget: wait a step
+        requests.
+
+        The step is the span ``serve.step`` (`serve.tracing`), with the
+        counts ``live`` (rows holding a request), ``wide`` (1 if a prompt
+        chunk widened it), ``tokens`` (tokens fed that the rows kept) and
+        ``prompt`` (of which prompt tokens)."""
+        with tracing.span("serve.step", step_num=self.steps) as sp:
+            events: list[StreamEvent] = list(self._pending_events)
+            self._pending_events.clear()
+            with tracing.span("serve.admit"):
+                self._admit(events)
+            rows = self._rows
+            if all(a is None for a in rows):
+                # unreachable: submit() rejects instead
+                if not self.sched.done:
+                    raise RuntimeError("scheduler stalled with waiting "
+                                       "requests and no active rows")
+                sp.set(live=0, wide=0, tokens=0, prompt=0)
+                return events
+            eng, pool, state = self.engine, self.pool, self.state
+            t = pool.page_tokens
+            chunk_rows: dict[int, tuple[int, bool]] = {}   # row -> (m, final)
+            wide = any(a is not None and a.prefilling for a in rows)
+            spec = self.spec_k > 1 or wide
+            n_rows = len(rows)      # mesh plan: max_active padded to dp blocks
+            if not spec:       # the spec branch derives these from srows
+                pos = np.zeros(n_rows, np.int32)
+                seq_ids = [-1] * n_rows
+                for i, act in enumerate(rows):
+                    if act is None:
                         continue
-                    budget -= 1
-                    # fill to the page boundary, never across it: one
-                    # chunk completes at most one page, so the fill path
-                    # in end_step sees whole pages exactly as decode does
-                    m = min(t - act.prefilled % t, len(act.pending))
-                    final = m == len(act.pending)
-                    chunk_rows[len(srows)] = (m, final)
-                    srows.append({"seq": act.seq, "pos": act.prefilled,
-                                  "chunk": act.pending[:m], "final": final})
-                    continue
-                srows.append({
-                    "seq": act.seq,
-                    "history": np.concatenate(
-                        [np.asarray(act.req.prompt, np.int32),
-                         np.asarray(act.outs, np.int32)]),
-                    "pos": act.pos, "eff_k": act.eff_k,
-                    "limit": act.req.max_new_tokens - len(act.outs),
-                    "eos": act.req.eos_token, "stats": act.stats})
-            self._key, sub = jax.random.split(self._key)
-            kept = eng._spec_step(state, step_fn, k, srows, sub)
-            if wide:
-                # the wide graph did not refresh the 1-token device
-                # feedback vector — rebuild it on the next plain step
-                self._rows_dirty = True
-                self._tok_dev = None
-        elif self._fused:
-            tok_in = self._tok_dev
-            if self._rows_dirty or tok_in is None:
-                # an admission (or a cancel) changed the row layout —
-                # rebuild the token vector once (run_fused counts the
-                # upload); steady-state steps feed the previous step's
-                # device tokens back
-                tok_in = np.zeros(n_rows, np.int32)
+                    pos[i] = act.pos
+                    seq_ids[i] = act.seq
+            t0 = time.perf_counter()
+            hits0 = (pool.stats["fast_hits"], pool.stats["slow_hits"])
+            g0 = state.gather_s
+            if spec:
+                # speculative verify step: k rows per live request, mixed
+                # freely with eff_k=1 (plain) rows and prefill chunk rows;
+                # tokens ride in the control block, so no device-token
+                # feedback is needed
+                k = max(self.spec_k, t) if wide else self.spec_k
+                step_fn = eng._fused_step_fn(state.slots, self.greedy,
+                                             self.temperature, k=k) \
+                    if wide else self._step_fn
+                budget = self.prefill_budget
+                srows: list[Optional[dict]] = []
+                for act in rows:
+                    if act is None:
+                        srows.append(None)
+                        continue
+                    if act.prefilling:
+                        if budget <= 0:
+                            srows.append(None)   # over budget: wait a step
+                            continue
+                        budget -= 1
+                        # fill to the page boundary, never across it: one
+                        # chunk completes at most one page, so the fill path
+                        # in end_step sees whole pages exactly as decode does
+                        m = min(t - act.prefilled % t, len(act.pending))
+                        final = m == len(act.pending)
+                        chunk_rows[len(srows)] = (m, final)
+                        srows.append({"seq": act.seq, "pos": act.prefilled,
+                                      "chunk": act.pending[:m],
+                                      "final": final})
+                        continue
+                    srows.append({
+                        "seq": act.seq,
+                        "history": np.concatenate(
+                            [np.asarray(act.req.prompt, np.int32),
+                             np.asarray(act.outs, np.int32)]),
+                        "pos": act.pos, "eff_k": act.eff_k,
+                        "limit": act.req.max_new_tokens - len(act.outs),
+                        "eos": act.req.eos_token, "stats": act.stats})
+                self._key, sub = jax.random.split(self._key)
+                kept = eng._spec_step(state, step_fn, k, srows, sub)
+                if wide:
+                    # the wide graph did not refresh the 1-token device
+                    # feedback vector — rebuild it on the next plain step
+                    self._rows_dirty = True
+                    self._tok_dev = None
+            elif self._fused:
+                tok_in = self._tok_dev
+                if self._rows_dirty or tok_in is None:
+                    # an admission (or a cancel) changed the row layout —
+                    # rebuild the token vector once (run_fused counts the
+                    # upload); steady-state steps feed the previous step's
+                    # device tokens back
+                    tok_in = np.zeros(n_rows, np.int32)
+                    for i, act in enumerate(rows):
+                        if act is not None:
+                            tok_in[i] = act.outs[-1]
+                    self._rows_dirty = False
+                self._key, sub = jax.random.split(self._key)
+                toks, self._tok_dev = state.run_fused(
+                    self._step_fn, eng.params, tok_in, seq_ids, pos, sub)
+            else:
+                tokens = np.zeros(n_rows, np.int32)
                 for i, act in enumerate(rows):
                     if act is not None:
-                        tok_in[i] = act.outs[-1]
-                self._rows_dirty = False
-            self._key, sub = jax.random.split(self._key)
-            toks, self._tok_dev = state.run_fused(
-                self._step_fn, eng.params, tok_in, seq_ids, pos, sub)
-        else:
-            tokens = np.zeros(n_rows, np.int32)
-            for i, act in enumerate(rows):
-                if act is not None:
-                    tokens[i] = act.outs[-1]
-            logits = paged_decode_step(eng.model, eng.params, tokens,
-                                       state, seq_ids, pos)
-            self._key, sub = jax.random.split(self._key)
-            toks = np.asarray(eng._sample(logits, self.greedy,
-                                          self.temperature, sub))
-        dt = time.perf_counter() - t0
-        eng.stats["decode_s"] += dt
-        eng.stats["decode_steps"] += 1
-        self.steps += 1
-        self.sched.observe_step(dt)   # service-rate EMA (deadline sheds)
-        if self._observe is not None:
-            self._observe(state.gather_s - g0,
-                          pool.stats["fast_hits"] - hits0[0],
-                          pool.stats["slow_hits"] - hits0[1])
-        decode_tokens = 0
-        for i, act in enumerate(rows):
-            if act is None:
-                continue
-            rec = self._recs[id(act.req)]
-            if i in chunk_rows:
-                m, final = chunk_rows[i]
-                act.prefilled += m
-                act.pending = act.pending[m:]
-                if not final:
-                    continue        # mid-prefill: nothing to stream yet
-                tok = int(kept[i][0])    # first generated token
-                act.outs.append(tok)
-                act.pending = None
-                eng.stats["tokens"] += 1
-                if self.radix and act.hashes:
-                    # prompt fully resident: pin its full pages so later
-                    # requests adopt them
-                    self.prefix_index.insert(
-                        act.hashes, self.sched.assigned_shard(act.req))
-                if rec.metrics is not None:
-                    rec.metrics.on_tokens(1)
-                done = act.finished
-                if done:
-                    self._finish(rec)
-                events.append(StreamEvent(act.req, [tok], done=done))
-                continue
-            if spec:
-                if kept[i] is None:      # over-budget prefill row idled
-                    continue
-                new = [int(x) for x in kept[i]]
-                act.outs.extend(new)
-            else:
-                new = [int(toks[i])]
-                act.outs.append(new[0])
-                act.stats.steps += 1
-                act.stats.tokens += 1
-            decode_tokens += len(new)
-            eng.stats["tokens"] += len(new)
-            if rec.metrics is not None:
-                rec.metrics.on_tokens(len(new))
-            done = act.finished
-            if done:
-                self._finish(rec)
-            events.append(StreamEvent(act.req, new, done=done))
-        if chunk_rows and decode_tokens:
-            # per-token wall time of decode work that shared its fused
-            # step with a prefill chunk — "decode p99 during admission"
-            self.prefill_step_decode_ms.append(dt * 1e3 / decode_tokens)
-        if self._preempt_observe is not None:
-            # per-step reward for the learned victim ranking: decode
-            # latency + the deadline misses the finishes above counted
-            self._preempt_observe(dt, self._step_misses)
-            self._step_misses = 0
-        if self._debug:     # REPRO_SERVE_DEBUG: per-step pool invariants
-            pins = self.prefix_index.pin_counts() \
-                if self.prefix_index is not None else None
-            pool.check_invariants(pins=pins)
-            if state._device is not None:
-                state._device.check_invariants()
-        self.peak_live_pages = max(self.peak_live_pages, pool.live_pages)
-        return events
+                        tokens[i] = act.outs[-1]
+                logits = paged_decode_step(eng.model, eng.params, tokens,
+                                           state, seq_ids, pos)
+                self._key, sub = jax.random.split(self._key)
+                sampled = eng._sample(logits, self.greedy,
+                                      self.temperature, sub)
+                with tracing.span("serve.device_wait"):
+                    toks = np.asarray(sampled)
+            dt = time.perf_counter() - t0
+            eng.stats["decode_s"] += dt
+            eng.stats["decode_steps"] += 1
+            self.steps += 1
+            self.sched.observe_step(dt)   # service-rate EMA (deadline sheds)
+            if self._observe is not None:
+                self._observe(state.gather_s - g0,
+                              pool.stats["fast_hits"] - hits0[0],
+                              pool.stats["slow_hits"] - hits0[1])
+            live = sum(a is not None for a in rows)
+            with tracing.span("serve.deliver"):
+                fed = prompt = 0
+                for i, act in enumerate(rows):
+                    if act is None:
+                        continue
+                    rec = self._recs[id(act.req)]
+                    if i in chunk_rows:
+                        m, final = chunk_rows[i]
+                        fed += m
+                        prompt += m
+                        act.prefilled += m
+                        act.pending = act.pending[m:]
+                        if not final:
+                            continue    # mid-prefill: nothing to stream yet
+                        tok = int(kept[i][0])    # first generated token
+                        act.outs.append(tok)
+                        act.pending = None
+                        eng.stats["tokens"] += 1
+                        if self.radix and act.hashes:
+                            # prompt fully resident: pin its full pages so
+                            # later requests adopt them
+                            self.prefix_index.insert(
+                                act.hashes, self.sched.assigned_shard(act.req))
+                        if rec.metrics is not None:
+                            rec.metrics.on_tokens(1)
+                        done = act.finished
+                        if done:
+                            self._finish(rec)
+                        events.append(StreamEvent(act.req, [tok], done=done))
+                        continue
+                    if spec:
+                        if kept[i] is None:   # over-budget prefill row idled
+                            continue
+                        new = [int(x) for x in kept[i]]
+                        act.outs.extend(new)
+                    else:
+                        new = [int(toks[i])]
+                        act.outs.append(new[0])
+                        act.stats.steps += 1
+                        act.stats.tokens += 1
+                    fed += len(new)
+                    eng.stats["tokens"] += len(new)
+                    if rec.metrics is not None:
+                        rec.metrics.on_tokens(len(new))
+                    done = act.finished
+                    if done:
+                        self._finish(rec)
+                    events.append(StreamEvent(act.req, new, done=done))
+            sp.set(live=live, wide=int(wide), tokens=fed, prompt=prompt)
+            if self._preempt_observe is not None:
+                # per-step reward for the learned victim ranking: decode
+                # latency + the deadline misses the finishes above counted
+                self._preempt_observe(dt, self._step_misses)
+                self._step_misses = 0
+            if self._debug:     # REPRO_SERVE_DEBUG: per-step pool invariants
+                pins = self.prefix_index.pin_counts() \
+                    if self.prefix_index is not None else None
+                pool.check_invariants(pins=pins)
+                if state._device is not None:
+                    state._device.check_invariants()
+            self.peak_live_pages = max(self.peak_live_pages, pool.live_pages)
+            return events

@@ -36,6 +36,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.serve import tracing
 from repro.serve.engine import ServeEngine, ServeSession
 from repro.serve.metrics import MetricsRegistry
 from repro.serve.scheduler import Admission, Request
@@ -234,21 +235,22 @@ class AsyncServeFrontend:
                     await self._wake.wait()
                     continue
                 events = self.session.step()
-                for ev in events:
-                    handle = self._handles.get(id(ev.request))
-                    if handle is None:        # cancelled mid-step
-                        continue
-                    handle._push(ev.tokens)
-                    if ev.error is not None:
-                        handle.error_reason = ev.error
-                    if ev.done:
-                        # a late pool-capacity rejection replaces the
-                        # admission verdict — refresh so handle.rejected
-                        # reflects it
-                        handle.admission = self.session.admission(
-                            ev.request)
-                        handle._finalize(self.session.result(ev.request))
-                        self._handles.pop(id(ev.request), None)
+                with tracing.span("serve.frontend.deliver"):
+                    for ev in events:
+                        handle = self._handles.get(id(ev.request))
+                        if handle is None:        # cancelled mid-step
+                            continue
+                        handle._push(ev.tokens)
+                        if ev.error is not None:
+                            handle.error_reason = ev.error
+                        if ev.done:
+                            # a late pool-capacity rejection replaces the
+                            # admission verdict — refresh so handle.rejected
+                            # reflects it
+                            handle.admission = self.session.admission(
+                                ev.request)
+                            handle._finalize(self.session.result(ev.request))
+                            self._handles.pop(id(ev.request), None)
                 # let submitters / consumers / cancellers interleave
                 await asyncio.sleep(0)
         except BaseException as e:

@@ -47,8 +47,6 @@ Page lifecycle (see serve/README.md):
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -59,6 +57,7 @@ from repro.kernels import api
 from repro.models.attention import decode_qkv
 from repro.models.layers import lm_head_apply, rms_norm
 from repro.models.transformer import mlp_tail
+from repro.serve import tracing
 from repro.serve.device_pool import DevicePagePool
 from repro.serve.kvcache import PagedKVPool
 from repro.serve.paged_state import (RecurrentStore, StateLayout,
@@ -357,106 +356,108 @@ class PagedKVState:
         control block so the whole verify step still costs ONE upload.
 
         Dead rows (seq -1) get the scratch slot and length 1."""
-        t0 = time.perf_counter()
-        t = self.pool.page_tokens
-        b = len(seq_ids)
-        if k > 1 and self._device is None:
-            raise RuntimeError("speculative (k > 1) steps scatter inside "
-                               "the fused graph — they need a device pool")
-        if k > t:
-            raise ValueError(
-                f"k={k} tokens per step exceed page_tokens={t}: one step "
-                f"may spill across at most one page boundary")
-        positions = np.broadcast_to(np.asarray(positions, np.int32), (b,))
-        s = self.slots
-        lay = self.layout
-        if lay is not None:
-            cc = lay.cols(s, k)
-            width = cc.width
-            c_tail, c_row, c_pos, c_len = cc.tail, cc.row, cc.pos, cc.len
-        else:
-            cc = None
-            width = s + 4 if k == 1 else s + 5 + k
-            # column offsets past the page table (k=1 keeps the PR-4 layout)
-            c_tail, c_row, c_pos, c_len = (s, s + 1, s + 2, s + 3) \
-                if k == 1 else (s, s + 2, s + 3, s + 4)
-        dev = self._device
-        shards = dev.shards if dev is not None else 1
-        if shards > 1 and b % shards:
-            raise ValueError(f"decode batch of {b} rows does not split "
-                             f"over {shards} data shards — pad with -1 "
-                             f"rows (ServePlan.pad_rows)")
-        # under shard_map every control value is shard-LOCAL: shard s sees
-        # only its block of rows and its capacity_local slot rows
-        row_shard = [i * shards // b for i in range(b)] if b else []
-        control = np.zeros((b, width), np.int32)
-        if dev is not None:
-            trash = np.array([dev.local_slot(self._trash[sh])
-                              for sh in row_shard], np.int32)
-            control[:, c_tail] = trash
-        control[:, c_len] = 1
-        if self._rec is not None:
-            # dead rows read/write the recurrent trash slot, and keep
-            # exactly 1 phantom token (keep_cap 0) so their garbage never
-            # escapes the trash row
-            control[:, cc.rec] = [self._rec.local_slot(self._rec.trash[sh])
-                                  for sh in row_shard]
-            if k > 1:
-                control[:, cc.keep_fixed] = 1
-                control[:, cc.keep_cap] = 0
-        if k > 1:
-            control[:, s + 1] = control[:, c_tail]            # spill slot
-            if tokens is not None:
-                control[:, s + 5:s + 5 + k] = np.asarray(tokens, np.int32)
-        groups_by_row, touch_pids = [], []
-        sync_groups, sync_shards = [], []
-        for i, seq in enumerate(seq_ids):
-            if seq < 0:
-                groups_by_row.append(None)
-                continue
-            if shards > 1:
-                self.bind_seq(seq, row_shard[i])
-            groups = self._page_groups(seq, tail_slots=1 if k == 1 else 2)
-            for g in groups:
-                touch_pids.extend(g)
-            sync_groups.extend(groups)
-            sync_shards.extend([row_shard[i]] * len(groups))
-            groups_by_row.append(groups)
-        self.pool.touch_many(touch_pids)
-        if dev is not None:
-            dev.sync(self.pool, sync_groups, sync_shards)
-        for i, groups in enumerate(groups_by_row):
-            if groups is None:
-                continue
-            seq = seq_ids[i]
-            tail = self.tail_len.get(seq, 0)
-            if dev is not None and self.num_layers:
-                sh = row_shard[i]
-                for n, g in enumerate(groups):
-                    control[i, n] = dev.local_slot(dev.slot(g[0], sh))
-                control[i, c_tail] = \
-                    dev.local_slot(self._ensure_tail_slot(seq))
-                control[i, len(groups)] = control[i, c_tail]
-                if k > 1:
-                    control[i, s + 1] = \
-                        dev.local_slot(self._ensure_spill_slot(seq))
-                    control[i, len(groups) + 1] = control[i, s + 1]
+        with tracing.span("serve.begin_step") as sp:
+            t = self.pool.page_tokens
+            b = len(seq_ids)
+            if k > 1 and self._device is None:
+                raise RuntimeError("speculative (k > 1) steps scatter "
+                                   "inside the fused graph — they need a "
+                                   "device pool")
+            if k > t:
+                raise ValueError(
+                    f"k={k} tokens per step exceed page_tokens={t}: one step "
+                    f"may spill across at most one page boundary")
+            positions = np.broadcast_to(np.asarray(positions, np.int32), (b,))
+            s = self.slots
+            lay = self.layout
+            if lay is not None:
+                cc = lay.cols(s, k)
+                width = cc.width
+                c_tail, c_row, c_pos, c_len = cc.tail, cc.row, cc.pos, cc.len
+            else:
+                cc = None
+                width = s + 4 if k == 1 else s + 5 + k
+                # column offsets past the page table (k=1 keeps the
+                # plain-decode layout)
+                c_tail, c_row, c_pos, c_len = (s, s + 1, s + 2, s + 3) \
+                    if k == 1 else (s, s + 2, s + 3, s + 4)
+            dev = self._device
+            shards = dev.shards if dev is not None else 1
+            if shards > 1 and b % shards:
+                raise ValueError(f"decode batch of {b} rows does not split "
+                                 f"over {shards} data shards — pad with -1 "
+                                 f"rows (ServePlan.pad_rows)")
+            # under shard_map every control value is shard-LOCAL: shard s sees
+            # only its block of rows and its capacity_local slot rows
+            row_shard = [i * shards // b for i in range(b)] if b else []
+            control = np.zeros((b, width), np.int32)
+            if dev is not None:
+                trash = np.array([dev.local_slot(self._trash[sh])
+                                  for sh in row_shard], np.int32)
+                control[:, c_tail] = trash
+            control[:, c_len] = 1
             if self._rec is not None:
-                control[i, cc.rec] = \
-                    self._rec.local_slot(self._ensure_rec_slot(seq))
+                # dead rows read/write the recurrent trash slot, and keep
+                # exactly 1 phantom token (keep_cap 0) so their garbage never
+                # escapes the trash row
+                control[:, cc.rec] = [self._rec.local_slot(self._rec.trash[sh])
+                                      for sh in row_shard]
                 if k > 1:
-                    control[i, cc.keep_fixed] = \
-                        -1 if keep_fixed is None else int(keep_fixed[i])
-                    control[i, cc.keep_cap] = \
-                        k - 1 if keep_cap is None else int(keep_cap[i])
-            if cc is not None and lay.has_ring:
-                control[i, cc.base] = self._ring_base.get(seq, 0)
-            control[i, c_row] = tail
-            control[i, c_pos] = positions[i]
-            control[i, c_len] = len(groups) * t + tail + 1
-        self._step = {"seq_ids": list(seq_ids), "control": control,
-                      "table": None, "lengths": None}
-        self.gather_s += time.perf_counter() - t0
+                    control[:, cc.keep_fixed] = 1
+                    control[:, cc.keep_cap] = 0
+            if k > 1:
+                control[:, s + 1] = control[:, c_tail]            # spill slot
+                if tokens is not None:
+                    control[:, s + 5:s + 5 + k] = np.asarray(tokens, np.int32)
+            groups_by_row, touch_pids = [], []
+            sync_groups, sync_shards = [], []
+            for i, seq in enumerate(seq_ids):
+                if seq < 0:
+                    groups_by_row.append(None)
+                    continue
+                if shards > 1:
+                    self.bind_seq(seq, row_shard[i])
+                groups = self._page_groups(seq, tail_slots=1 if k == 1 else 2)
+                for g in groups:
+                    touch_pids.extend(g)
+                sync_groups.extend(groups)
+                sync_shards.extend([row_shard[i]] * len(groups))
+                groups_by_row.append(groups)
+            self.pool.touch_many(touch_pids)
+            if dev is not None:
+                dev.sync(self.pool, sync_groups, sync_shards)
+            for i, groups in enumerate(groups_by_row):
+                if groups is None:
+                    continue
+                seq = seq_ids[i]
+                tail = self.tail_len.get(seq, 0)
+                if dev is not None and self.num_layers:
+                    sh = row_shard[i]
+                    for n, g in enumerate(groups):
+                        control[i, n] = dev.local_slot(dev.slot(g[0], sh))
+                    control[i, c_tail] = \
+                        dev.local_slot(self._ensure_tail_slot(seq))
+                    control[i, len(groups)] = control[i, c_tail]
+                    if k > 1:
+                        control[i, s + 1] = \
+                            dev.local_slot(self._ensure_spill_slot(seq))
+                        control[i, len(groups) + 1] = control[i, s + 1]
+                if self._rec is not None:
+                    control[i, cc.rec] = \
+                        self._rec.local_slot(self._ensure_rec_slot(seq))
+                    if k > 1:
+                        control[i, cc.keep_fixed] = \
+                            -1 if keep_fixed is None else int(keep_fixed[i])
+                        control[i, cc.keep_cap] = \
+                            k - 1 if keep_cap is None else int(keep_cap[i])
+                if cc is not None and lay.has_ring:
+                    control[i, cc.base] = self._ring_base.get(seq, 0)
+                control[i, c_row] = tail
+                control[i, c_pos] = positions[i]
+                control[i, c_len] = len(groups) * t + tail + 1
+            self._step = {"seq_ids": list(seq_ids), "control": control,
+                          "table": None, "lengths": None}
+        self.gather_s += sp.elapsed
         return control
 
     def _step_view(self):
@@ -475,22 +476,20 @@ class PagedKVState:
         host values (one extra upload: the first step, or a continuous
         admission). Returns ``(host_tokens, device_tokens)``."""
         control = self.begin_step(seq_ids, positions)
-        # one logical upload either way; a mesh plan pins the layout so the
-        # jit ingests each shard's rows without a gather-and-reshard
-        if self.plan is not None:
-            cdev = jax.device_put(control, self.plan.control_sharding())
-        else:
-            cdev = jnp.asarray(control)
-        self.h2d += 1
-        if not isinstance(tokens, jax.Array):
-            tokens = np.asarray(tokens, np.int32)
-            tokens = jnp.asarray(tokens) if self.plan is None else \
-                jax.device_put(tokens, self.plan.token_sharding())
-            self.h2d += 1
-        tok_dev, arrays = step_fn(params, self.device_arrays, tokens,
-                                  cdev, key)
-        self.adopt_device_arrays(arrays)
-        tok_host = np.asarray(tok_dev)
+        with tracing.span("serve.dispatch"):
+            # one logical upload either way; a mesh plan pins the layout so
+            # the jit ingests each shard's rows without a gather-and-reshard
+            cdev = self._upload_control(control)
+            if not isinstance(tokens, jax.Array):
+                tokens = np.asarray(tokens, np.int32)
+                tokens = jnp.asarray(tokens) if self.plan is None else \
+                    jax.device_put(tokens, self.plan.token_sharding())
+                self.h2d += 1
+            tok_dev, arrays = step_fn(params, self.device_arrays, tokens,
+                                      cdev, key)
+            self.adopt_device_arrays(arrays)
+        with tracing.span("serve.device_wait"):
+            tok_host = np.asarray(tok_dev)
         self.d2h += 1
         self.end_step(seq_ids)
         return tok_host, tok_dev
@@ -517,16 +516,21 @@ class PagedKVState:
                                   k=int(np.asarray(tokens_k).shape[1]),
                                   tokens=tokens_k, keep_fixed=keep_fixed,
                                   keep_cap=keep_cap)
-        if self.plan is not None:
-            cdev = jax.device_put(control, self.plan.control_sharding())
-        else:
-            cdev = jnp.asarray(control)
-        self.h2d += 1
-        out_dev, arrays = step_fn(params, self.device_arrays, cdev, key)
-        self.adopt_device_arrays(arrays)
-        out = np.asarray(out_dev)
+        with tracing.span("serve.dispatch"):
+            cdev = self._upload_control(control)
+            out_dev, arrays = step_fn(params, self.device_arrays, cdev, key)
+            self.adopt_device_arrays(arrays)
+        with tracing.span("serve.device_wait"):
+            out = np.asarray(out_dev)
         self.d2h += 1
         return out
+
+    def _upload_control(self, control: np.ndarray):
+        """The step's one control upload."""
+        self.h2d += 1
+        if self.plan is not None:
+            return jax.device_put(control, self.plan.control_sharding())
+        return jnp.asarray(control)
 
     def append_step_rows(self, layer: int, k_rows: np.ndarray,
                          v_rows: np.ndarray):
@@ -558,10 +562,10 @@ class PagedKVState:
             return api.run("paged_attention", q, *self._device.arrays,
                            st["table"], st["lengths"],
                            jnp.int32(layer), backend=backend)
-        t0 = time.perf_counter()
-        view = self._gather_numpy(layer, st["seq_ids"])
-        self.gather_s += time.perf_counter() - t0   # the restack IS the
-        self.h2d += len(view)                       # Sibyl-visible latency
+        with tracing.span("serve.gather") as sp:
+            view = self._gather_numpy(layer, st["seq_ids"])
+        self.gather_s += sp.elapsed     # the restack IS the
+        self.h2d += len(view)           # Sibyl-visible latency
         return api.run("paged_attention", q,
                        *[jnp.asarray(a) for a in view], backend=backend)
 
@@ -585,61 +589,61 @@ class PagedKVState:
         tokens cross the page boundary, the spill slot (which already
         holds their scattered rows) is promoted to be the new tail slot.
         Default: 1 token per live row (the plain decode path)."""
-        t0 = time.perf_counter()
-        t = self.pool.page_tokens
-        if advanced is None:
-            advanced = [1] * len(seq_ids)
-        for seq, adv in zip(seq_ids, advanced):
-            if seq < 0 or adv == 0:
-                continue
-            if not 0 < adv <= t:
-                raise ValueError(
-                    f"sequence {seq}: advanced {adv} tokens in one step "
-                    f"(valid: 1..page_tokens={t})")
-            if self.num_layers == 0:
-                continue            # pure-recurrent stack: no pages to fill
-            n = self.tail_len.get(seq, 0) + adv
-            if n < t:
-                self.tail_len[seq] = n
+        with tracing.span("serve.end_step") as sp:
+            t = self.pool.page_tokens
+            if advanced is None:
+                advanced = [1] * len(seq_ids)
+            for seq, adv in zip(seq_ids, advanced):
+                if seq < 0 or adv == 0:
+                    continue
+                if not 0 < adv <= t:
+                    raise ValueError(
+                        f"sequence {seq}: advanced {adv} tokens in one step "
+                        f"(valid: 1..page_tokens={t})")
+                if self.num_layers == 0:
+                    continue    # pure-recurrent stack: no pages to fill
+                n = self.tail_len.get(seq, 0) + adv
+                if n < t:
+                    self.tail_len[seq] = n
+                    if self.layout is not None and self.layout.has_ring:
+                        self._drop_ring(seq)
+                    continue
+                self.tail_len[seq] = n - t
+                if self._device is not None:
+                    slot = self._tail_slot.pop(seq)
+                    k_all, v_all = self._device.read_slot(slot)
+                    # a chunked prefill queued this page's cumulative prompt
+                    # hash: store it shared (identical content dedups onto a
+                    # live/pinned page; `adopt` then recycles the tail slot)
+                    pending = self._pending_hashes.get(seq)
+                    h = pending.pop(0) if pending else None
+                    group = tuple(
+                        self.pool.put(seq, k_all[l], v_all[l], layer=l,
+                                      content_hash=h)
+                        for l in range(self.num_layers))
+                    self._device.adopt(group, slot, self.pool,
+                                       self._device.shard_of_slot(slot))
+                    spill = self._spill_slot.pop(seq, None)
+                    if spill is not None:
+                        # rows past the boundary were scattered here already
+                        self._tail_slot[seq] = spill
+                    elif n > t:
+                        raise RuntimeError(
+                            f"sequence {seq}: {n - t} tokens crossed the page "
+                            f"boundary without a spill slot — multi-token "
+                            f"steps must begin_step with k > 1")
+                else:
+                    if adv != 1:
+                        raise RuntimeError("multi-token steps need the device "
+                                           "pool (decode_mode='fused')")
+                    for l in range(self.num_layers):
+                        rows = self.tail_data.pop((seq, l))
+                        self.pool.put(seq, np.stack([r[0] for r in rows]),
+                                      np.stack([r[1] for r in rows]), layer=l)
                 if self.layout is not None and self.layout.has_ring:
                     self._drop_ring(seq)
-                continue
-            self.tail_len[seq] = n - t
-            if self._device is not None:
-                slot = self._tail_slot.pop(seq)
-                k_all, v_all = self._device.read_slot(slot)
-                # a chunked prefill queued this page's cumulative prompt
-                # hash: store it shared (identical content dedups onto a
-                # live/pinned page; `adopt` then recycles the tail slot)
-                pending = self._pending_hashes.get(seq)
-                h = pending.pop(0) if pending else None
-                group = tuple(
-                    self.pool.put(seq, k_all[l], v_all[l], layer=l,
-                                  content_hash=h)
-                    for l in range(self.num_layers))
-                self._device.adopt(group, slot, self.pool,
-                                   self._device.shard_of_slot(slot))
-                spill = self._spill_slot.pop(seq, None)
-                if spill is not None:
-                    # rows past the boundary were scattered here already
-                    self._tail_slot[seq] = spill
-                elif n > t:
-                    raise RuntimeError(
-                        f"sequence {seq}: {n - t} tokens crossed the page "
-                        f"boundary without a spill slot — multi-token "
-                        f"steps must begin_step with k > 1")
-            else:
-                if adv != 1:
-                    raise RuntimeError("multi-token steps need the device "
-                                       "pool (decode_mode='fused')")
-                for l in range(self.num_layers):
-                    rows = self.tail_data.pop((seq, l))
-                    self.pool.put(seq, np.stack([r[0] for r in rows]),
-                                  np.stack([r[1] for r in rows]), layer=l)
-            if self.layout is not None and self.layout.has_ring:
-                self._drop_ring(seq)
-        self._step = None
-        self.gather_s += time.perf_counter() - t0
+            self._step = None
+        self.gather_s += sp.elapsed
 
     def _drop_ring(self, seq: int):
         """Ring recycling: retire front pages every query position can no
@@ -789,9 +793,9 @@ class PagedKVState:
         if self.mode != "numpy":
             raise RuntimeError("gather() assembles host arrays — device-"
                                "resident modes use begin_step()/attend()")
-        t0 = time.perf_counter()
-        view = self._gather_numpy(layer, list(seq_ids))
-        self.gather_s += time.perf_counter() - t0
+        with tracing.span("serve.gather") as sp:
+            view = self._gather_numpy(layer, list(seq_ids))
+        self.gather_s += sp.elapsed
         return view
 
     def _seq_view_numpy(self, seq, layer):
@@ -1006,13 +1010,24 @@ def _mlp_tail_tp(cfg, kind, p, x, tp):
     return x + y
 
 
-def _wrap_step(step, model, plan, *, control_spec, out_spec, layout=None):
-    """jit the step; under a mesh plan, shard_map it first: params by the
-    serve partition rules, pool + recurrent-store arrays by the kernel's
-    head-sharded calling convention, decode rows over "data".
+def _mixer_scope(mixer) -> str:
+    """`jax.named_scope` of a layer's token mixer in the fused step; the
+    step's other scopes are "mlp" and "logits" (final norm, lm head,
+    sampling and, k > 1, the accept rule)."""
+    return "attention" if mixer in (ATTN, LOCAL_ATTN) else "recurrence"
+
+
+def _wrap_step(step, model, plan, *, k, control_spec, out_spec,
+               layout=None):
+    """jit the step as ``fused_step_k<k>`` (its XLA module is
+    ``jit_fused_step_k<k>``, so a profile tells the widths apart); under
+    a mesh plan, shard_map it first: params by the serve partition rules,
+    pool + recurrent-store arrays by the kernel's head-sharded calling
+    convention, decode rows over "data".
     check_vma=False because the body's donated scatters + psum seams are
     not replication-safe to infer; correctness is asserted by the
     sharded-vs-single-device equivalence tests."""
+    step.__name__ = step.__qualname__ = f"fused_step_k{k}"
     if plan is None:
         return jax.jit(step, donate_argnums=(1,))
     from jax.sharding import PartitionSpec as P
@@ -1104,7 +1119,7 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
     def tail_rows_of(i):
         return lay.tail_kv[i], lay.tail_ssd[i], lay.tail_rg[i]
 
-    def step(params, arrays, tokens, control, key):
+    def fused_step(params, arrays, tokens, control, key):
         kv = tuple(arrays[:6])
         rec = list(arrays[6:])
         kf, vf, kq, vq, ks, vs = kv
@@ -1124,54 +1139,56 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
             x, kf, vf = carry[0], carry[1], carry[2]
             rec = list(carry[3:])
             mixer, _mlp = kind
-            h = rms_norm(x, p["norm1"])
-            if mixer in (ATTN, LOCAL_ATTN):
-                ap = p["attn"]
-                q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
-                idx = row_kv * (c * t) + row_base
-                kf = kf.reshape(flat_kv).at[idx] \
-                    .set(k_new[:, 0].astype(kf.dtype)).reshape(kf.shape)
-                vf = vf.reshape(flat_kv).at[idx] \
-                    .set(v_new[:, 0].astype(vf.dtype)).reshape(vf.shape)
-                if mixer == ATTN:
-                    y = api.run("paged_attention", q[:, 0], kf, vf, kq, vq,
-                                ks, vs, table, lengths,
-                                jnp.asarray(row_kv, jnp.int32),
-                                backend=backend)
-                else:
-                    k_all, v_all = gather_ring_kv((kf, vf, kq, vq, ks, vs),
-                                                  row_kv, table)
-                    y = ring_attend(q, k_all, v_all, lengths=lengths,
-                                    base=ring_base,
-                                    positions=positions[:, None],
-                                    window=lay.window, page_tokens=t)[:, 0]
-                y = jnp.einsum("bhk,hkd->bd", y.astype(x.dtype), ap["wo"])
-                if tp > 1:      # complete the head-sharded partial sum
-                    y = jax.lax.psum(y, "model")
-                x = x + y[:, None]
-            elif mixer == SSD:
-                ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
-                state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
-                          rec_gather(rec[ib], row_ssd, rec_slots))
-                y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
-                                            tp=tp)
-                rec[ia] = rec_scatter(rec[ia], row_ssd, rec_slots,
-                                      states[0][0])
-                rec[ib] = rec_scatter(rec[ib], row_ssd, rec_slots,
-                                      states[1][0])
-                x = x + y
-            else:               # RGLRU
-                ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
-                state0 = (rec_gather(rec[ia], row_rg, rec_slots),
-                          rec_gather(rec[ib], row_rg, rec_slots))
-                y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
-                                            state0, tp=tp)
-                rec[ia] = rec_scatter(rec[ia], row_rg, rec_slots,
-                                      states[0][0])
-                rec[ib] = rec_scatter(rec[ib], row_rg, rec_slots,
-                                      states[1][0])
-                x = x + y
-            x = _mlp_tail_tp(cfg, kind, p, x, tp)
+            with jax.named_scope(_mixer_scope(mixer)):
+                h = rms_norm(x, p["norm1"])
+                if mixer in (ATTN, LOCAL_ATTN):
+                    ap = p["attn"]
+                    q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
+                    idx = row_kv * (c * t) + row_base
+                    kf = kf.reshape(flat_kv).at[idx] \
+                        .set(k_new[:, 0].astype(kf.dtype)).reshape(kf.shape)
+                    vf = vf.reshape(flat_kv).at[idx] \
+                        .set(v_new[:, 0].astype(vf.dtype)).reshape(vf.shape)
+                    if mixer == ATTN:
+                        y = api.run("paged_attention", q[:, 0], kf, vf, kq, vq,
+                                    ks, vs, table, lengths,
+                                    jnp.asarray(row_kv, jnp.int32),
+                                    backend=backend)
+                    else:
+                        k_all, v_all = gather_ring_kv((kf, vf, kq, vq, ks, vs),
+                                                      row_kv, table)
+                        y = ring_attend(q, k_all, v_all, lengths=lengths,
+                                        base=ring_base,
+                                        positions=positions[:, None],
+                                        window=lay.window, page_tokens=t)[:, 0]
+                    y = jnp.einsum("bhk,hkd->bd", y.astype(x.dtype), ap["wo"])
+                    if tp > 1:      # complete the head-sharded partial sum
+                        y = jax.lax.psum(y, "model")
+                    x = x + y[:, None]
+                elif mixer == SSD:
+                    ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
+                    state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
+                              rec_gather(rec[ib], row_ssd, rec_slots))
+                    y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
+                                                tp=tp)
+                    rec[ia] = rec_scatter(rec[ia], row_ssd, rec_slots,
+                                          states[0][0])
+                    rec[ib] = rec_scatter(rec[ib], row_ssd, rec_slots,
+                                          states[1][0])
+                    x = x + y
+                else:               # RGLRU
+                    ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
+                    state0 = (rec_gather(rec[ia], row_rg, rec_slots),
+                              rec_gather(rec[ib], row_rg, rec_slots))
+                    y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
+                                                state0, tp=tp)
+                    rec[ia] = rec_scatter(rec[ia], row_rg, rec_slots,
+                                          states[0][0])
+                    rec[ib] = rec_scatter(rec[ib], row_rg, rec_slots,
+                                          states[1][0])
+                    x = x + y
+            with jax.named_scope("mlp"):
+                x = _mlp_tail_tp(cfg, kind, p, x, tp)
             return (x, kf, vf, *rec)
 
         def group_body(carry, xs):
@@ -1189,19 +1206,20 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
         x, kf, vf = carry[0], carry[1], carry[2]
         rec = list(carry[3:])
 
-        x = rms_norm(x, params["final_norm"])
-        logits = lm_head_apply(cfg, params["embed"], x)[:, 0]
-        if greedy:
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            if dp > 1:      # independent noise per data shard's rows
-                key = jax.random.fold_in(key, jax.lax.axis_index("data"))
-            tok = jax.random.categorical(key, logits / temperature,
-                                         axis=-1).astype(jnp.int32)
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["final_norm"])
+            logits = lm_head_apply(cfg, params["embed"], x)[:, 0]
+            if greedy:
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                if dp > 1:      # independent noise per data shard's rows
+                    key = jax.random.fold_in(key, jax.lax.axis_index("data"))
+                tok = jax.random.categorical(key, logits / temperature,
+                                             axis=-1).astype(jnp.int32)
         return tok, (kf, vf, kq, vq, ks, vs, *rec)
 
     from jax.sharding import PartitionSpec as P
-    return _wrap_step(step, model, plan,
+    return _wrap_step(fused_step, model, plan, k=1,
                       control_spec=(P("data"), P("data", None)),
                       out_spec=P("data"),
                       layout=lay if n_rec else None)
@@ -1299,7 +1317,7 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
                 None if ssd_r is None else g * lay.ssd_per_group + ssd_r,
                 None if rg_r is None else g * lay.rg_per_group + rg_r)
 
-    def step(params, arrays, control, key):
+    def fused_step(params, arrays, control, key):
         kv = tuple(arrays[:6])
         rec = list(arrays[6:])
         kf, vf, kq, vq, ks, vs = kv
@@ -1331,52 +1349,54 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
             layers, else the stacked (k, b, ...) candidate-state leaves
             the post-accept checkpoint commit selects from."""
             mixer, _mlp = kind
-            h = rms_norm(x, p["norm1"])
-            if mixer in (ATTN, LOCAL_ATTN):
-                ap = p["attn"]
-                q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
-                idx = (row_kv * (c * t) + row_base).reshape(-1)  # (b * k,)
-                b = k_new.shape[0]
-                kf = kf.reshape(flat_kv).at[idx] \
-                    .set(k_new.reshape((b * k,) + k_new.shape[2:])
-                         .astype(kf.dtype)).reshape(kf.shape)
-                vf = vf.reshape(flat_kv).at[idx] \
-                    .set(v_new.reshape((b * k,) + v_new.shape[2:])
-                         .astype(vf.dtype)).reshape(vf.shape)
-                if mixer == ATTN:
-                    # ONE KV pass scores all k rows (multi-query-row
-                    # kernel path: row j masks to lengths + j)
-                    y = api.run("paged_attention", q, kf, vf, kq, vq, ks,
-                                vs, table, lengths,
-                                jnp.asarray(row_kv, jnp.int32),
-                                backend=backend)
-                else:
-                    k_all, v_all = gather_ring_kv((kf, vf, kq, vq, ks, vs),
-                                                  row_kv, table)
-                    y = ring_attend(q, k_all, v_all, lengths=lengths,
-                                    base=ring_base, positions=positions,
-                                    window=lay.window, page_tokens=t)
-                y = jnp.einsum("bshk,hkd->bsd", y.astype(x.dtype),
-                               ap["wo"])
-                if tp > 1:      # complete the head-sharded partial sum
-                    y = jax.lax.psum(y, "model")
-                x = x + y
-                states = None
-            elif mixer == SSD:
-                ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
-                state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
-                          rec_gather(rec[ib], row_ssd, rec_slots))
-                y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
-                                            tp=tp)
-                x = x + y
-            else:               # RGLRU
-                ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
-                state0 = (rec_gather(rec[ia], row_rg, rec_slots),
-                          rec_gather(rec[ib], row_rg, rec_slots))
-                y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
-                                            state0, tp=tp)
-                x = x + y
-            x = _mlp_tail_tp(cfg, kind, p, x, tp)
+            with jax.named_scope(_mixer_scope(mixer)):
+                h = rms_norm(x, p["norm1"])
+                if mixer in (ATTN, LOCAL_ATTN):
+                    ap = p["attn"]
+                    q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
+                    idx = (row_kv * (c * t) + row_base).reshape(-1)  # (b * k,)
+                    b = k_new.shape[0]
+                    kf = kf.reshape(flat_kv).at[idx] \
+                        .set(k_new.reshape((b * k,) + k_new.shape[2:])
+                             .astype(kf.dtype)).reshape(kf.shape)
+                    vf = vf.reshape(flat_kv).at[idx] \
+                        .set(v_new.reshape((b * k,) + v_new.shape[2:])
+                             .astype(vf.dtype)).reshape(vf.shape)
+                    if mixer == ATTN:
+                        # ONE KV pass scores all k rows (multi-query-row
+                        # kernel path: row j masks to lengths + j)
+                        y = api.run("paged_attention", q, kf, vf, kq, vq, ks,
+                                    vs, table, lengths,
+                                    jnp.asarray(row_kv, jnp.int32),
+                                    backend=backend)
+                    else:
+                        k_all, v_all = gather_ring_kv((kf, vf, kq, vq, ks, vs),
+                                                      row_kv, table)
+                        y = ring_attend(q, k_all, v_all, lengths=lengths,
+                                        base=ring_base, positions=positions,
+                                        window=lay.window, page_tokens=t)
+                    y = jnp.einsum("bshk,hkd->bsd", y.astype(x.dtype),
+                                   ap["wo"])
+                    if tp > 1:      # complete the head-sharded partial sum
+                        y = jax.lax.psum(y, "model")
+                    x = x + y
+                    states = None
+                elif mixer == SSD:
+                    ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
+                    state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
+                              rec_gather(rec[ib], row_ssd, rec_slots))
+                    y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
+                                                tp=tp)
+                    x = x + y
+                else:               # RGLRU
+                    ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
+                    state0 = (rec_gather(rec[ia], row_rg, rec_slots),
+                              rec_gather(rec[ib], row_rg, rec_slots))
+                    y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
+                                                state0, tp=tp)
+                    x = x + y
+            with jax.named_scope("mlp"):
+                x = _mlp_tail_tp(cfg, kind, p, x, tp)
             return x, kf, vf, states
 
         def group_body(carry, xs):
@@ -1401,22 +1421,23 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
             if st is not None:
                 tail_states.append(st)
 
-        x = rms_norm(x, params["final_norm"])
-        logits = lm_head_apply(cfg, params["embed"], x)      # (b, k, V)
-        if greedy:
-            samp = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            if dp > 1:      # independent noise per data shard's rows
-                key = jax.random.fold_in(key, jax.lax.axis_index("data"))
-            samp = jax.random.categorical(key, logits / temperature,
-                                          axis=-1).astype(jnp.int32)
-        # accept rule: draft j (input column j, j >= 1) survives while it
-        # equals the model's sampled token after the previous position —
-        # the count of the all-match prefix, exactly the tokens the
-        # autoregressive path would have produced
-        match = (tokens[:, 1:] == samp[:, :-1]).astype(jnp.int32)
-        n_acc = jnp.cumprod(match, axis=1).sum(axis=1)
-        verdict = jnp.concatenate([samp, n_acc[:, None]], axis=1)
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["final_norm"])
+            logits = lm_head_apply(cfg, params["embed"], x)      # (b, k, V)
+            if greedy:
+                samp = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                if dp > 1:      # independent noise per data shard's rows
+                    key = jax.random.fold_in(key, jax.lax.axis_index("data"))
+                samp = jax.random.categorical(key, logits / temperature,
+                                              axis=-1).astype(jnp.int32)
+            # accept rule: draft j (input column j, j >= 1) survives while it
+            # equals the model's sampled token after the previous position —
+            # the count of the all-match prefix, exactly the tokens the
+            # autoregressive path would have produced
+            match = (tokens[:, 1:] == samp[:, :-1]).astype(jnp.int32)
+            n_acc = jnp.cumprod(match, axis=1).sum(axis=1)
+            verdict = jnp.concatenate([samp, n_acc[:, None]], axis=1)
 
         if lay.has_rec:
             # commit the per-row state checkpoint: chunked-prefill rows
@@ -1433,7 +1454,7 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
         return verdict, (kf, vf, kq, vq, ks, vs, *rec)
 
     from jax.sharding import PartitionSpec as P
-    return _wrap_step(step, model, plan,
+    return _wrap_step(fused_step, model, plan, k=k,
                       control_spec=(P("data", None),),
                       out_spec=P("data", None),
                       layout=lay if rec_names else None)

@@ -23,14 +23,20 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
 
+from repro.serve import tracing
 from repro.serve.frontend import AsyncServeFrontend
 from repro.serve.metrics import MetricsRegistry, percentile
 from repro.serve.scheduler import Request
 
+# a step's device round trip: the spans from the control block to the
+# bookkeeping after the tokens are back
+ROUND_TRIP = ("serve.begin_step", "serve.dispatch", "serve.device_wait",
+              "serve.end_step")
 
 @dataclasses.dataclass
 class TraceSpec:
@@ -182,6 +188,7 @@ async def replay(engine, spec: TraceSpec, *, max_active: int = 4,
                 break
         await handle.result()
 
+    t_start = time.perf_counter()
     async with front:
         loop = asyncio.get_running_loop()
         t0 = loop.time()
@@ -196,6 +203,7 @@ async def replay(engine, spec: TraceSpec, *, max_active: int = 4,
                         deadline=item.deadline, priority=item.priority))
             tasks.append(asyncio.create_task(consume(item, handle)))
         await asyncio.gather(*tasks)
+    t_end = time.perf_counter()
 
     out = metrics.summary()
     out["mix"] = spec.name
@@ -209,8 +217,16 @@ async def replay(engine, spec: TraceSpec, *, max_active: int = 4,
     # the chunked admissions (None when the mix never chunk-prefilled)
     out["prefix_hit_rate"] = front.session.prefix_hit_rate
     # per-token wall time of decode steps that shared their fused launch
-    # with a prefill chunk — "decode p99 while a long prompt admits"
-    ms = front.session.prefill_step_decode_ms
+    # with a prefill chunk — "decode p99 while a long prompt admits";
+    # a step's time is its device round trip, begin_step to end_step
+    ran = tracing.spans(t_start, t_end)
+    decode = {s.index: s.counts["tokens"] - s.counts["prompt"]
+              for s in ran if s.name == "serve.step" and s.counts["prompt"]}
+    took = {i: 0.0 for i, n in decode.items() if n > 0}
+    for s in ran:
+        if s.parent in took and s.name in ROUND_TRIP:
+            took[s.parent] += s.elapsed
+    ms = [t * 1e3 / decode[i] for i, t in took.items()]
     out["decode_p99_during_prefill_ms"] = percentile(ms, 99) if ms else None
     # cancellation correctness: every cancelled (and finished) request's
     # pages must be freed — anything still live leaked
