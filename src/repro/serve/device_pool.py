@@ -26,11 +26,14 @@ written in full on (re)assignment — a recycled slot can never leak a
 previous occupant's other-tier content into the sum. Tier is per
 (layer, page): one group may mix fast and slow cells across layers.
 
-Sync is incremental and versioned: a page is rewritten only when it is
-new to the mirror or its `Page.version` changed (LRU demotion bumps it).
-Write batches are padded to the next power of two (duplicate trailing
-indices — last write wins on identical data) so jit caches a bounded set
-of scatter shapes as the pool grows.
+Sync is incremental and versioned: a page is written when its group is
+new to the mirror, and rewritten in place when its `Page.version`
+changes (LRU demotion, swap). The pool reports those pages
+(`PagedKVPool.watch`), so a sync visits the groups it is handed and the
+pages in ``stale``, never the whole mirror. Write batches are padded to
+the next power of two (duplicate trailing indices — last write wins on
+identical data) so jit caches a bounded set of scatter shapes as the
+pool grows.
 """
 from __future__ import annotations
 
@@ -174,7 +177,9 @@ class DevicePagePool:
         # multi-shard pool keys by (shard, pid) while the 1-shard pool
         # keeps the plain pid keys its tests and callers know
         self.slot_of: dict = {}
-        self._synced: dict = {}                     # same keying -> version
+        # same keying, per page of every layer -> (version, slot) written
+        self._synced: dict = {}
+        self.stale: set[int] = set()    # pids whose version changed (pool)
         self._dirty: set[int] = set()               # slots ever written
         self.writes = 0     # device scatter calls (bench/test instrumentation)
         self.reads = 0      # device->host pulls (fill readbacks)
@@ -242,6 +247,7 @@ class DevicePagePool:
         deduped onto an existing page — chunked prefill rebuilding a
         cached prompt page) keeps its synced slot and the incoming tail
         slot is recycled instead of leaking."""
+        pool.watch(self)
         key = self._key(group[0], shard)
         prev = self.slot_of.get(key)
         if prev is not None and prev != slot:
@@ -251,7 +257,7 @@ class DevicePagePool:
         for pid in group:
             page = pool.pages[pid]
             if page.tier == "fast":
-                self._synced[self._key(pid, shard)] = page.version
+                self._synced[self._key(pid, shard)] = (page.version, slot)
 
     # -- content writes ------------------------------------------------------
     def zero_slot(self, slot: int):
@@ -322,13 +328,15 @@ class DevicePagePool:
 
     # -- sync ----------------------------------------------------------------
     def sync(self, pool, groups, shards=None):
-        """Bring the mirror current for an iterable of page groups (each a
-        tuple of per-layer pids): allocate a slot for groups new to the
-        mirror, rewrite (layer, slot) cells whose page version changed
-        (demotions). Batched into at most one fast + one slow scatter.
+        """Bring the mirror current: allocate a slot for each page group
+        (a tuple of per-layer pids) new to the mirror and write its cells,
+        and rewrite the cells of every mapped page whose version changed
+        since it was written (the pool's ``stale`` reports: demotions,
+        swaps). Batched into at most one fast + one slow scatter.
         `shards` (aligned with `groups`, default all 0) pins each group to
         the data shard whose rows attend it — the slot comes from that
         shard's range and the sync record is keyed per shard."""
+        pool.watch(self)
         groups = list(groups)
         if shards is None:
             shards = [0] * len(groups)
@@ -346,27 +354,39 @@ class DevicePagePool:
             fresh.append((group, shard))
             if key not in self.slot_of:
                 self.slot_of[key] = self.alloc(shard)
+        cells = [(layer, pid, shard, self.slot_of[self._key(group[0], shard)])
+                 for group, shard in fresh
+                 for layer, pid in enumerate(group)]
+        for pid in self.stale:
+            page = pool.pages.get(pid)
+            if page is None or page.tier == "host":
+                continue    # destroyed, or parked: swap-in bumps it again
+            for shard in range(self.shards):
+                rec = self._synced.get(self._key(pid, shard))
+                if rec is not None:
+                    cells.append((page.layer, pid, shard, rec[1]))
+        self.stale.clear()
+        if not cells:
+            return
         fast_w, slow_w = [], []
         c = self.capacity
-        for group, shard in fresh:
-            slot = self.slot_of[self._key(group[0], shard)]
-            for layer, pid in enumerate(group):
-                page = pool.pages[pid]
-                key = self._key(pid, shard)
-                if self._synced.get(key) == page.version:
-                    continue
-                if page.tier == "host":
-                    raise RuntimeError(
-                        f"sync asked to mirror parked (host-tier) page {pid}"
-                        " — swap the sequence in before scheduling it")
-                idx = layer * c + slot
-                if page.tier == "fast":
-                    k, v = page.data
-                    fast_w.append((idx, k, v))
-                else:
-                    (kq, ks), (vq, vs) = page.data
-                    slow_w.append((idx, kq, ks[..., 0], vq, vs[..., 0]))
-                self._synced[key] = page.version
+        for layer, pid, shard, slot in cells:
+            page = pool.pages[pid]
+            key = self._key(pid, shard)
+            if self._synced.get(key) == (page.version, slot):
+                continue
+            if page.tier == "host":
+                raise RuntimeError(
+                    f"sync asked to mirror parked (host-tier) page {pid}"
+                    " — swap the sequence in before scheduling it")
+            idx = layer * c + slot
+            if page.tier == "fast":
+                k, v = page.data
+                fast_w.append((idx, k, v))
+            else:
+                (kq, ks), (vq, vs) = page.data
+                slow_w.append((idx, kq, ks[..., 0], vq, vs[..., 0]))
+            self._synced[key] = (page.version, slot)
         if fast_w:
             idx = np.array([w[0] for w in fast_w], np.int32)
             k = np.stack([w[1] for w in fast_w]).astype(np.float32)

@@ -1393,6 +1393,7 @@ class ServeSession:
                 pins = self.prefix_index.pin_counts() \
                     if self.prefix_index is not None else None
                 pool.check_invariants(pins=pins)
+                state.check_invariants()
                 if state._device is not None:
                     state._device.check_invariants()
             self.peak_live_pages = max(self.peak_live_pages, pool.live_pages)

@@ -75,8 +75,6 @@ class Page:
     tier: str          # "fast" | "slow" | "host" (swapped out, no mirror)
     quantized: bool
     layer: int = 0     # model layer the page belongs to
-    access_count: int = 0
-    last_access: int = 0
     data: Optional[tuple] = None   # (k, v) or ((kq, ks), (vq, vs))
     refs: int = 1                  # holders (prefix-shared pages: > 1)
     content_hash: Optional[tuple] = None   # (layer, token-prefix hash)
@@ -95,6 +93,10 @@ def _data_nbytes(data) -> int:
     return total
 
 
+# tier codes of the per-pid `_tier` array (touch_many counts hits from it)
+_TIER = {"fast": 0, "slow": 1, "host": 2}
+
+
 class PagedKVPool:
     """Page-granular KV store with tier placement decided by a policy object
     (heuristic or Sibyl RL agent). The slow tier stores pages int8-quantized.
@@ -110,6 +112,14 @@ class PagedKVPool:
     and a resumed sequence decodes token-for-token as if never preempted.
     Host pages don't count against `headroom()` and are unreachable via
     `page_by_hash` (no dedup or radix pin can land on a parked page).
+
+    Recency is an integer stamp per page id (``_stamp``): every access
+    stamps the page above all earlier ones, and LRU demotion takes the
+    fast page with the lowest stamp. `touch_many` stamps a whole decode
+    step's pages in one numpy assignment, in first-occurrence order, so
+    the recency order is exactly that of one ``move_to_end`` per page.
+    Mirrors registered with `watch` get the id of every page whose
+    ``version`` changes (demotion, swap) in their ``stale`` set.
     """
 
     # every live pool, for test-teardown invariant sweeps (conftest)
@@ -125,8 +135,16 @@ class PagedKVPool:
         self._by_seq: dict[tuple, list[int]] = {}   # (seq, layer) -> pids
         self._by_hash: dict[tuple, int] = {}        # (layer, hash) -> pid
         # fast-tier pages in LRU order (oldest first) — eviction pops the
-        # head in O(1) instead of rescanning every page per victim
+        # head in O(1) instead of rescanning every page per victim. A
+        # batched touch moves only stamps (``_lru_stale``); the next
+        # eviction re-sorts the dict by stamp once
         self._fast_lru: OrderedDict[int, None] = OrderedDict()
+        self._lru_stale = False
+        # per page id (ids are never reused): recency stamp, tier code
+        self._stamp = np.zeros(64, np.int64)
+        self._tier = np.zeros(64, np.int8)
+        self._ticks = 0               # next recency stamp
+        self._mirrors: "weakref.WeakSet" = weakref.WeakSet()
         self.clock = 0
         self.next_id = 0
         self.host_pages = 0           # pages currently in the "host" tier
@@ -164,6 +182,29 @@ class PagedKVPool:
         if self.recorder is not None:
             self.recorder.record(page.page_id, page.nbytes / 1024.0, is_write)
 
+    def _recent(self, pid: int) -> None:
+        """Stamp one page as the most recently used."""
+        self._stamp[pid] = self._ticks
+        self._ticks += 1
+        if pid in self._fast_lru:
+            self._fast_lru.move_to_end(pid)
+
+    def _set_tier(self, page: Page, tier: str) -> None:
+        page.tier = tier
+        self._tier[page.page_id] = _TIER[tier]
+
+    def _bump(self, page: Page) -> None:
+        """A page's resident representation changed: its mirrors must
+        rewrite it."""
+        page.version += 1
+        for mirror in self._mirrors:
+            mirror.stale.add(page.page_id)
+
+    def watch(self, mirror) -> None:
+        """Register a device mirror: from now on the id of every page
+        whose version changes lands in ``mirror.stale``."""
+        self._mirrors.add(mirror)
+
     def put(self, seq_id: int, k: np.ndarray, v: np.ndarray,
             layer: int = 0, content_hash=None) -> int:
         """Store one page for (seq_id, layer). With a `content_hash` (a
@@ -176,21 +217,25 @@ class PagedKVPool:
             if pid is not None:
                 page = self.pages[pid]
                 page.refs += 1
-                page.last_access = self.clock
-                if page.tier == "fast":
-                    self._fast_lru.move_to_end(pid)
+                self._recent(pid)
                 self._by_seq.setdefault((seq_id, layer), []).append(pid)
                 self.stats["shared_puts"] += 1
                 self._record(page, is_write=False)
                 return pid
         pid = self.next_id
         self.next_id += 1
+        if pid == len(self._stamp):
+            self._stamp = np.concatenate([self._stamp,
+                                          np.zeros_like(self._stamp)])
+            self._tier = np.concatenate([self._tier,
+                                         np.zeros_like(self._tier)])
         feats = self._features(seq_id)
         tier = "fast"
         if self.policy is not None:
             tier = self.policy.place(feats)
         page = Page(pid, seq_id, tier, quantized=(tier == "slow"),
-                    layer=layer, last_access=self.clock)
+                    layer=layer)
+        self._tier[pid] = _TIER[tier]
         if tier == "slow":
             page.data = (quantize_page(k), quantize_page(v))
         else:
@@ -203,6 +248,7 @@ class PagedKVPool:
         self._by_seq.setdefault((seq_id, layer), []).append(pid)
         if tier == "fast":
             self._fast_lru[pid] = None
+        self._recent(pid)
         self.stats[f"{tier}_bytes"] += page.nbytes
         self._record(page, is_write=True)
         self._maybe_evict()
@@ -212,10 +258,8 @@ class PagedKVPool:
         """Per-page access bookkeeping (hit stats, LRU recency, recorder)
         at the current clock — the clock tick itself is the caller's."""
         page = self.pages[pid]
-        page.access_count += 1
-        page.last_access = self.clock
+        self._recent(pid)
         if page.tier == "fast":
-            self._fast_lru.move_to_end(pid)
             self.stats["fast_hits"] += 1
         elif page.tier == "host":
             self.stats["host_hits"] += 1
@@ -237,10 +281,33 @@ class PagedKVPool:
         once per (pid, step) — not once per layer — so the clock-phase
         recency feature the Sibyl policy sees advances in decode steps,
         not in (layers x pages) micro-events, and hit stats count each
-        page read once per token."""
+        page read once per token.
+
+        The pages are stamped at once, in first-occurrence order; an
+        attached ``recorder`` needs one record per page, so with one the
+        pages are touched one by one."""
         self.clock += 1
-        for pid in dict.fromkeys(pids):
-            self._touch_page(pid)
+        if self.recorder is not None:
+            for pid in dict.fromkeys(int(p) for p in pids):
+                self._touch_page(pid)
+            return
+        pids = np.asarray(pids, np.int64)
+        if not len(pids):
+            return
+        uniq, first = np.unique(pids, return_index=True)
+        self._stamp[uniq] = self._ticks + first
+        self._ticks += len(pids)
+        hits = np.bincount(self._tier[uniq], minlength=3)
+        self.stats["fast_hits"] += int(hits[0])
+        self.stats["slow_hits"] += int(hits[1])
+        self.stats["host_hits"] += int(hits[2])
+        if hits[0]:
+            self._lru_stale = True
+
+    def lru_order(self) -> list[int]:
+        """Fast-tier page ids, least recently used first: the order LRU
+        demotion takes them in."""
+        return sorted(self._fast_lru, key=lambda pid: self._stamp[pid])
 
     def get(self, pid: int):
         page = self.touch(pid)
@@ -287,9 +354,7 @@ class PagedKVPool:
         self.clock += 1
         page = self.pages[pid]
         page.refs += 1
-        page.last_access = self.clock
-        if page.tier == "fast":
-            self._fast_lru.move_to_end(pid)
+        self._recent(pid)
         self._by_seq.setdefault((seq_id, layer), []).append(pid)
         self.stats["adopted_pages"] += 1
         self._record(page, is_write=False)
@@ -406,8 +471,8 @@ class PagedKVPool:
                 if page.tier == "fast":
                     self._fast_lru.pop(pid, None)
                 page.resident_tier = page.tier
-                page.tier = "host"
-                page.version += 1
+                self._set_tier(page, "host")
+                self._bump(page)
                 if page.content_hash is not None and \
                         self._by_hash.get(page.content_hash) == pid:
                     del self._by_hash[page.content_hash]
@@ -436,8 +501,9 @@ class PagedKVPool:
                 if page.tier != "host":
                     continue
                 tier = page.resident_tier or "slow"
-                page.tier, page.resident_tier = tier, None
-                page.version += 1
+                self._set_tier(page, tier)
+                page.resident_tier = None
+                self._bump(page)
                 self.host_pages -= 1
                 self.stats["host_bytes"] -= page.nbytes
                 self.stats[f"{tier}_bytes"] += page.nbytes
@@ -445,6 +511,7 @@ class PagedKVPool:
                 self.stats["swap_in_bytes"] += page.nbytes
                 if tier == "fast":
                     self._fast_lru[pid] = None
+                    self._recent(pid)
                 if page.content_hash is not None:
                     self._by_hash.setdefault(page.content_hash, pid)
                 restored.append((pid, page.layer))
@@ -481,6 +548,8 @@ class PagedKVPool:
                     f"page {pid}: refs={page.refs} < holders {held}"
             assert (pid in self._fast_lru) == (page.tier == "fast"), \
                 f"page {pid}: tier {page.tier} vs LRU membership mismatch"
+            assert self._tier[pid] == _TIER[page.tier], \
+                f"page {pid}: tier {page.tier} vs tier code {self._tier[pid]}"
             if page.tier == "host":
                 n_host += 1
                 assert page.resident_tier in ("fast", "slow"), \
@@ -505,6 +574,9 @@ class PagedKVPool:
             assert self.stats[f"{tier}_bytes"] == total, \
                 (f"{tier}_bytes stat {self.stats[f'{tier}_bytes']} != "
                  f"live sum {total}")
+        if not self._lru_stale:
+            assert list(self._fast_lru) == self.lru_order(), \
+                "fast LRU order disagrees with the recency stamps"
         for h, pid in self._by_hash.items():
             page = self.pages.get(pid)
             assert page is not None, f"_by_hash[{h}] names dead page {pid}"
@@ -515,14 +587,19 @@ class PagedKVPool:
 
     def _maybe_evict(self):
         # O(1) per victim: pop the LRU head instead of rescanning the pool
+        if len(self._fast_lru) > self.fast_capacity and self._lru_stale:
+            # batched touches moved stamps only: restore the dict's order
+            self._fast_lru = OrderedDict.fromkeys(self.lru_order())
+            self._lru_stale = False
         while len(self._fast_lru) > self.fast_capacity:
             pid, _ = self._fast_lru.popitem(last=False)
             victim = self.pages[pid]
             k, v = victim.data
             self.stats["fast_bytes"] -= victim.nbytes
             victim.data = (quantize_page(k), quantize_page(v))
-            victim.tier, victim.quantized = "slow", True
-            victim.version += 1            # device mirror must rewrite
+            self._set_tier(victim, "slow")
+            victim.quantized = True
+            self._bump(victim)             # device mirror must rewrite
             victim.nbytes = _data_nbytes(victim.data)
             self.stats["slow_bytes"] += victim.nbytes
             self.stats["evictions"] += 1

@@ -47,6 +47,8 @@ Page lifecycle (see serve/README.md):
 """
 from __future__ import annotations
 
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -77,6 +79,39 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+class _TableRow:
+    """One sequence's page-table row, kept current by the events that
+    change its pages (prefill, fills, ring drops) rather than rebuilt
+    from the pool every step. ``pids[:n]`` are its layer-uniform page
+    groups in page order (row-major: the flat pid order a step touches);
+    ``slots[:synced]`` the shard-local device slot of each group the
+    mirror has synced — groups past ``synced`` get theirs at the next
+    `begin_step`'s sync."""
+
+    __slots__ = ("pids", "slots", "n", "synced")
+
+    def __init__(self, groups: np.ndarray, capacity: int):
+        # room for the whole page table: a step's overflow check leaves
+        # at least one tail slot free, and a step fills at most one page
+        n, layers = groups.shape
+        self.pids = np.zeros((max(n, capacity), layers), np.int64)
+        self.pids[:n] = groups
+        self.slots = np.zeros(len(self.pids), np.int32)
+        self.n = n
+        self.synced = 0
+
+    def append(self, group) -> None:
+        self.pids[self.n] = group
+        self.n += 1
+
+    def drop_front(self, d: int) -> None:
+        n = self.n
+        self.pids[:n - d] = self.pids[d:n]
+        self.slots[:n - d] = self.slots[d:n]
+        self.n -= d
+        self.synced = max(0, self.synced - d)
+
+
 class PagedKVState:
     """Pool-backed KV state for a decode batch.
 
@@ -94,7 +129,16 @@ class PagedKVState:
     tensor transfers this state (and its device pool) performs on the
     decode path — the quantity the fused step minimizes and
     `bench_serve` / the transfer-count tests report.
+
+    Each live sequence's page-table row (`_TableRow`) is built from the
+    pool once — at its first step, after a prefill write or a prefix
+    adoption, and after a swap-in — and from then on changed in place
+    by the page events: a filled tail appends a group, a ring drop
+    removes front groups. A step copies the rows into its control block.
     """
+
+    # every live state, for test-teardown invariant sweeps (conftest)
+    _instances: "weakref.WeakSet[PagedKVState]" = weakref.WeakSet()
 
     def __init__(self, pool: PagedKVPool, capacity: int, num_layers: int,
                  hkv: int, hd: int, mode: str = "fused",
@@ -148,6 +192,7 @@ class PagedKVState:
         self._rec_slot: dict[int, int] = {}    # seq -> GLOBAL rec slot
         self._parked_rec: dict[int, dict] = {}  # seq -> parked state blocks
         self._ring_base: dict[int, int] = {}   # seq -> dropped ring pages
+        self._table: dict[int, _TableRow] = {}  # seq -> page-table row
         self._device: DevicePagePool | None = None
         self._trash = 0
         if mode != "numpy":
@@ -167,6 +212,7 @@ class PagedKVState:
         self.gather_s = 0.0       # host-side bookkeeping time (Sibyl reward)
         self.h2d = 0              # control/token uploads owned by the state
         self.d2h = 0
+        PagedKVState._instances.add(self)
 
     # -- data-shard binding --------------------------------------------------
     def bind_seq(self, seq: int, shard: int):
@@ -224,6 +270,7 @@ class PagedKVState:
         full pages at the front are assumed already present (adopted from
         the radix prefix index) and are not re-put; the tail-row math is
         unchanged."""
+        self._table.pop(seq, None)      # rebuilt from the pool at its step
         t = self.pool.page_tokens
         n_full = k.shape[0] // t
         for p in range(n_full):
@@ -264,6 +311,7 @@ class PagedKVState:
         if prev != 0 or self.pool.seq_pages(seq, 0):
             raise RuntimeError(f"sequence {seq}: adopt_prefix must run "
                                f"before any prefill write")
+        self._table.pop(seq, None)
         for group in groups:
             for layer, pid in enumerate(group):
                 self.pool.adopt_page(seq, pid, layer)
@@ -313,13 +361,9 @@ class PagedKVState:
         self._rec.write_slot(slot, blocks)
 
     # -- per-step protocol ---------------------------------------------------
-    def _page_groups(self, seq: int, tail_slots: int = 1):
-        """Per-layer pool pids of each logical page of `seq`, zipped into
-        layer-uniform groups, with the slot-overflow check (+ the tail
-        slot(s) every decode step appends into — 2 for speculative steps,
-        whose rows may cross one page boundary)."""
-        if self.num_layers == 0:       # pure-recurrent stack: no KV pages
-            return []
+    def _build_row(self, seq: int) -> _TableRow:
+        """`seq`'s page-table row built from the pool: the per-layer pool
+        pids of each logical page, zipped into layer-uniform groups."""
         per_layer = [self.pool.seq_pages(seq, l)
                      for l in range(self.num_layers)]
         n = len(per_layer[0])
@@ -328,20 +372,40 @@ class PagedKVState:
                 f"sequence {seq}: ragged page counts across layers "
                 f"({[len(p) for p in per_layer]}) — paged decode requires "
                 f"layer-uniform page structure")
-        if n + tail_slots > self.slots:
-            raise ValueError(
-                f"sequence {seq}: {n} pages + {tail_slots} tail page(s) "
-                f"exceed the page-table capacity of {self.slots} slots "
-                f"({self.slots * self.pool.page_tokens} tokens); size the "
-                f"PagedKVState capacity to the longest request")
-        return list(zip(*per_layer)) if n else []
+        groups = np.array(per_layer, np.int64).T.reshape(n,
+                                                         self.num_layers)
+        return _TableRow(groups, self.slots)
+
+    def check_invariants(self) -> None:
+        """Structural self-check (debug mode and every test teardown):
+        each cached page-table row equals the one built from the pool,
+        and each synced group's slot is the one the mirror maps."""
+        dev = self._device
+        for seq, row in self._table.items():
+            want = self._build_row(seq)
+            assert row.n == want.n and np.array_equal(
+                row.pids[:row.n], want.pids[:want.n]), \
+                (f"sequence {seq}: cached page groups "
+                 f"{row.pids[:row.n].tolist()} != the pool's "
+                 f"{want.pids[:want.n].tolist()}")
+            if dev is None:
+                continue
+            shard = self._shard_of.get(seq, 0)
+            for j in range(row.synced):
+                slot = dev.local_slot(dev.slot(int(row.pids[j, 0]), shard))
+                assert row.slots[j] == slot, \
+                    (f"sequence {seq}: cached slot {row.slots[j]} of page "
+                     f"{j} != the mirror's {slot}")
 
     def begin_step(self, seq_ids, positions, k: int = 1,
                    tokens=None, keep_fixed=None, keep_cap=None) -> np.ndarray:
         """Host bookkeeping before one decode step: touch each live page
         once (one pool-clock tick for the whole step), sync the device
-        mirror (new prefill pages, demotion rewrites), and build the
-        layer-uniform control block the fused step consumes.
+        mirror (page groups new since the row's last step, demotion
+        rewrites), and copy each row's cached page-table row into the
+        layer-uniform control block the fused step consumes. The span
+        ``serve.begin_step`` counts the live ``rows`` and those
+        ``rebuilt`` from the pool this step (admission, swap-in).
 
         ``k == 1`` (plain decode): ``(b, slots + 4)`` int32 rows
         ``[page table | tail slot | tail row | position | kv length]``,
@@ -409,39 +473,57 @@ class PagedKVState:
                 control[:, s + 1] = control[:, c_tail]            # spill slot
                 if tokens is not None:
                     control[:, s + 5:s + 5 + k] = np.asarray(tokens, np.int32)
-            groups_by_row, touch_pids = [], []
-            sync_groups, sync_shards = [], []
+            tail_slots = 1 if k == 1 else 2
+            live, rebuilt = [], 0       # (batch row, seq, table row)
             for i, seq in enumerate(seq_ids):
                 if seq < 0:
-                    groups_by_row.append(None)
                     continue
                 if shards > 1:
                     self.bind_seq(seq, row_shard[i])
-                groups = self._page_groups(seq, tail_slots=1 if k == 1 else 2)
-                for g in groups:
-                    touch_pids.extend(g)
-                sync_groups.extend(groups)
-                sync_shards.extend([row_shard[i]] * len(groups))
-                groups_by_row.append(groups)
-            self.pool.touch_many(touch_pids)
+                row = None
+                if self.num_layers:     # pure-recurrent stacks: no pages
+                    row = self._table.get(seq)
+                    if row is None:
+                        row = self._table[seq] = self._build_row(seq)
+                        rebuilt += 1
+                    if row.n + tail_slots > self.slots:
+                        raise ValueError(
+                            f"sequence {seq}: {row.n} pages + {tail_slots} "
+                            f"tail page(s) exceed the page-table capacity "
+                            f"of {self.slots} slots ({self.slots * t} "
+                            f"tokens); size the PagedKVState capacity to "
+                            f"the longest request")
+                live.append((i, seq, row))
+            rows = [row for _, _, row in live if row is not None]
+            self.pool.touch_many(np.concatenate(
+                [row.pids[:row.n].ravel() for row in rows]) if rows else ())
             if dev is not None:
-                dev.sync(self.pool, sync_groups, sync_shards)
-            for i, groups in enumerate(groups_by_row):
-                if groups is None:
-                    continue
-                seq = seq_ids[i]
+                # only groups new since the row's last step (and pages the
+                # pool reports changed) reach the mirror
+                new = [(i, row) for i, _, row in live
+                       if row is not None and row.synced < row.n]
+                dev.sync(self.pool,
+                         [tuple(g) for _, row in new
+                          for g in row.pids[row.synced:row.n].tolist()],
+                         [row_shard[i] for i, row in new
+                          for _ in range(row.synced, row.n)])
+                for i, row in new:
+                    for j in range(row.synced, row.n):
+                        row.slots[j] = dev.local_slot(
+                            dev.slot(int(row.pids[j, 0]), row_shard[i]))
+                    row.synced = row.n
+            for i, seq, row in live:
+                n = row.n if row is not None else 0
                 tail = self.tail_len.get(seq, 0)
                 if dev is not None and self.num_layers:
-                    sh = row_shard[i]
-                    for n, g in enumerate(groups):
-                        control[i, n] = dev.local_slot(dev.slot(g[0], sh))
+                    control[i, :n] = row.slots[:n]
                     control[i, c_tail] = \
                         dev.local_slot(self._ensure_tail_slot(seq))
-                    control[i, len(groups)] = control[i, c_tail]
+                    control[i, n] = control[i, c_tail]
                     if k > 1:
                         control[i, s + 1] = \
                             dev.local_slot(self._ensure_spill_slot(seq))
-                        control[i, len(groups) + 1] = control[i, s + 1]
+                        control[i, n + 1] = control[i, s + 1]
                 if self._rec is not None:
                     control[i, cc.rec] = \
                         self._rec.local_slot(self._ensure_rec_slot(seq))
@@ -454,7 +536,8 @@ class PagedKVState:
                     control[i, cc.base] = self._ring_base.get(seq, 0)
                 control[i, c_row] = tail
                 control[i, c_pos] = positions[i]
-                control[i, c_len] = len(groups) * t + tail + 1
+                control[i, c_len] = n * t + tail + 1
+            sp.set(rows=len(live), rebuilt=rebuilt)
             self._step = {"seq_ids": list(seq_ids), "control": control,
                           "table": None, "lengths": None}
         self.gather_s += sp.elapsed
@@ -623,6 +706,7 @@ class PagedKVState:
                         for l in range(self.num_layers))
                     self._device.adopt(group, slot, self.pool,
                                        self._device.shard_of_slot(slot))
+                    self._append_group(seq, group)
                     spill = self._spill_slot.pop(seq, None)
                     if spill is not None:
                         # rows past the boundary were scattered here already
@@ -636,14 +720,24 @@ class PagedKVState:
                     if adv != 1:
                         raise RuntimeError("multi-token steps need the device "
                                            "pool (decode_mode='fused')")
+                    group = []
                     for l in range(self.num_layers):
                         rows = self.tail_data.pop((seq, l))
-                        self.pool.put(seq, np.stack([r[0] for r in rows]),
-                                      np.stack([r[1] for r in rows]), layer=l)
+                        group.append(self.pool.put(
+                            seq, np.stack([r[0] for r in rows]),
+                            np.stack([r[1] for r in rows]), layer=l))
+                    self._append_group(seq, group)
                 if self.layout is not None and self.layout.has_ring:
                     self._drop_ring(seq)
             self._step = None
         self.gather_s += sp.elapsed
+
+    def _append_group(self, seq: int, group) -> None:
+        """A filled tail became page group ``group``: append it to the
+        sequence's table row (its slot comes with the next sync)."""
+        row = self._table.get(seq)
+        if row is not None:
+            row.append(group)
 
     def _drop_ring(self, seq: int):
         """Ring recycling: retire front pages every query position can no
@@ -658,6 +752,7 @@ class PagedKVState:
         n_pages = len(self.pool.seq_pages(seq, 0))
         last_pos = (base + n_pages) * t + self.tail_len.get(seq, 0) - 1
         target = lay.ring_base(last_pos)
+        dropped = 0
         while base < target and n_pages > 0:
             for l in range(self.num_layers):
                 for pid, _layer in self.pool.drop_front(seq, l):
@@ -665,13 +760,18 @@ class PagedKVState:
                         self._device.release_pid(pid)
             base += 1
             n_pages -= 1
+            dropped += 1
         self._ring_base[seq] = base
+        row = self._table.get(seq)
+        if dropped and row is not None:
+            row.drop_front(dropped)
 
     def release_page(self, pid: int):
         """Recycle a destroyed pool page's device slot — the radix
         prefix tree hooks this (``on_release``) so an evicted/cleared
         pin frees its device slot exactly like `free_seq` does for a
-        retired sequence's pages."""
+        retired sequence's pages. A destroyed page has no holder left,
+        so no table row names it."""
         if self._device is not None:
             self._device.release_pid(pid)
 
@@ -691,6 +791,7 @@ class PagedKVState:
         are counted in the pool's ``swap_out_bytes`` stat)."""
         if seq in self._parked_tail:
             raise RuntimeError(f"sequence {seq} is already swapped out")
+        self._table.pop(seq, None)      # its slots go: rebuilt at swap-in
         tail_bytes = 0
         if self._device is not None:
             n = self.tail_len.get(seq, 0)
@@ -772,6 +873,7 @@ class PagedKVState:
         self._parked_tail.pop(seq, None)
         self._parked_rec.pop(seq, None)
         self._ring_base.pop(seq, None)
+        self._table.pop(seq, None)
         if self._rec is not None:
             slot = self._rec_slot.pop(seq, None)
             if slot is not None:

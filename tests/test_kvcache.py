@@ -62,11 +62,13 @@ def test_hit_and_eviction_stats_accounting(rng):
         pool.get(pid)
     assert pool.stats["fast_hits"] == 2            # the 2 surviving fast
     assert pool.stats["slow_hits"] == 2            # the 2 demoted
-    assert all(pool.pages[pid].access_count == 1 for pid in ids)
+    assert pool.lru_order() == [ids[2], ids[3]]    # in access order
     # touch() records a hit without dequantizing
+    pool.touch(ids[2])
+    assert pool.stats["fast_hits"] == 3
+    assert pool.lru_order() == [ids[3], ids[2]]
     pool.touch(ids[0])
     assert pool.stats["slow_hits"] == 3
-    assert pool.pages[ids[0]].access_count == 2
 
 
 def test_touch_many_ticks_clock_once_per_step(rng):
@@ -79,10 +81,9 @@ def test_touch_many_ticks_clock_once_per_step(rng):
     pids = [pool.put(0, _page(rng), _page(rng), layer=layer)
             for layer in range(3)]
     c0 = pool.clock
-    pool.touch_many(pids + pids)                   # duplicates deduped
+    pool.touch_many(pids[::-1] + pids)             # duplicates deduped
     assert pool.clock == c0 + 1
-    assert all(pool.pages[p].last_access == pool.clock for p in pids)
-    assert all(pool.pages[p].access_count == 1 for p in pids)
+    assert pool.lru_order() == pids[::-1]          # first occurrence wins
     assert pool.stats["fast_hits"] == 3
     pool.touch_many([])                            # an all-dead step still
     assert pool.clock == c0 + 2                    # advances step time

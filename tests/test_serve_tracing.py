@@ -26,7 +26,8 @@ from serving import harness, spec, steplog, tiny  # noqa: E402
 # the benchmark metrics that read the spans
 SPAN_METRICS = {"host_serial_share", "host_serial_share.batch",
                 "begin_step_ms", "end_step_ms", "session_host_ms",
-                "frontend_gap_ms"}
+                "frontend_gap_ms", "page_table_reuse_share",
+                "page_table_reuse_share.batch"}
 CHILDREN = ("serve.admit", "serve.begin_step", "serve.dispatch",
             "serve.device_wait", "serve.end_step", "serve.deliver")
 
@@ -220,7 +221,8 @@ def test_traced_tiny_run_reports_every_span_metric(cell, tmp_path):
     assert want and want <= set(res["metrics"])
     vals = {k: res["metrics"][k]["value"] for k in want}
     assert all(np.isfinite(v) for v in vals.values()), vals
-    for k in ("host_serial_share", "host_serial_share.batch"):
+    for k in ("host_serial_share", "host_serial_share.batch",
+              "page_table_reuse_share", "page_table_reuse_share.batch"):
         if k in vals:
             assert 0.0 <= vals[k] <= 100.0
     for k in ("begin_step_ms", "end_step_ms", "session_host_ms",

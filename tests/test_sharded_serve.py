@@ -213,6 +213,34 @@ def test_sharded_steady_state_two_transfers_per_token(cfg):
     assert (h1 - h0, d1 - d0) == (3, 3)
 
 
+@needs8
+def test_control_block_matches_the_pool_on_a_2x2_mesh(cfg, params):
+    """The page-table rows kept by page events, on shard-local slots:
+    every step's control block equals the one rebuilt from the pool and
+    the mirror (`test_page_table.reference_control`) through chunked
+    prefill with a shared head, a preemption and its resume."""
+    from test_page_table import check_every_step
+
+    eng = _engine(cfg, params, (2, 2))
+    ses = ServeSession(eng, capacity=64, max_active=4)
+    counts = check_every_step(ses.state)
+    rng = np.random.default_rng(3)
+    head = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
+    reqs = [Request(np.concatenate([head, rng.integers(
+        0, cfg.vocab_size, n).astype(np.int32)]), new)
+        for n, new in ((5, 9), (11, 6), (3, 8), (7, 7))]
+    for r in reqs:
+        ses.submit(r)
+    for _ in range(5):
+        ses.step()
+    assert ses.preempt(reqs[0])
+    while not ses.done:
+        ses.step()
+    assert ses.resumes == 1
+    assert 0 < sum(b for _, b in counts) < sum(r for r, _ in counts)
+    ses.close()
+
+
 # ---------------------------------------------------------------------------
 # Kernel calling convention: per-shard calls are fully local
 # ---------------------------------------------------------------------------

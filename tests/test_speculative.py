@@ -172,8 +172,8 @@ def test_rollback_never_puts_phantom_tokens(cfg, params):
     for i, (r, o) in enumerate(zip(reqs, outs)):
         want = (len(r.prompt) + len(o) - 1) // t
         assert len(eng.kv_pool.seq_pages(i, 0)) == want, (i, want)
-    # per-layer structure stays uniform (ragged counts would raise in
-    # _page_groups, but assert the end state too)
+    # per-layer structure stays uniform (ragged counts would raise when a
+    # table row is built from the pool, but assert the end state too)
     by_layer = {}
     for p in eng.kv_pool.pages.values():
         by_layer[p.layer] = by_layer.get(p.layer, 0) + 1
