@@ -8,6 +8,7 @@ from repro.configs import (
     mamba2_780m,
     minicpm3_4b,
     musicgen_medium,
+    nemotron_h_47b,
     qwen3_moe_30b_a3b,
     recurrentgemma_2b,
     starcoder2_7b,

@@ -17,6 +17,7 @@ LOCAL_ATTN = "local_attn"  # sliding-window attention
 CROSS_ATTN = "cross_attn"  # self-attn layer augmented with cross-attention
 SSD = "ssd"              # mamba2 state-space-duality mixer
 RGLRU = "rglru"          # RG-LRU recurrent block (with short conv)
+MIXER_NONE = "none"      # no token mixer: the layer is its MLP alone
 
 MLP_DENSE = "dense"
 MLP_MOE = "moe"
@@ -36,12 +37,16 @@ class ModelConfig:
     head_dim: int = 0
     d_ff: int = 0
     rope_theta: float = 10_000.0
+    rope: bool = True                # False: attention without position
+                                     # encoding (other layers carry it)
     window: int = 0                  # sliding window size for LOCAL_ATTN
     qkv_bias: bool = False
     qk_norm: bool = False            # RMS-norm q/k per head (qwen3 style)
-    # layer pattern: repeated until num_layers is covered.
+    # layer pattern: repeated until num_layers is covered, starting at
+    # entry first_layer (a pipeline stage of a longer published stack).
     # each entry: (mixer_kind, mlp_kind)
     pattern: Sequence[tuple] = ((ATTN, MLP_DENSE),)
+    first_layer: int = 0
     # MoE
     num_experts: int = 0
     top_k: int = 0
@@ -66,7 +71,8 @@ class ModelConfig:
     external_embed: bool = False     # audio: inputs are precomputed frame embeddings
     n_img_tokens: int = 0            # vlm: number of patch-embedding tokens
     cross_attn_every: int = 0        # vlm: a cross-attn layer every N layers
-    mlp_gelu: bool = False           # classic 2-matmul GELU FFN instead of SwiGLU
+    mlp_act: str = "swiglu"          # swiglu | gelu | relu2 (the last two:
+                                     # classic 2-matmul FFN, no gate)
     # numerics
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
@@ -91,17 +97,18 @@ class ModelConfig:
                 else:
                     out.append((ATTN, MLP_DENSE))
             return out
-        i = 0
+        i = self.first_layer
         while len(out) < self.num_layers:
             out.append(self.pattern[i % len(self.pattern)])
             i += 1
         return out
 
     def group_size(self) -> int:
-        """Layers per scan step (period of the layer pattern)."""
+        """Layers per scan step (period of the layer pattern; the whole
+        stack when it is shorter than one period)."""
         if self.cross_attn_every:
             return self.cross_attn_every
-        return len(self.pattern)
+        return min(len(self.pattern), self.num_layers)
 
     @property
     def attention_based(self) -> bool:
@@ -122,7 +129,7 @@ class ModelConfig:
             n += self.vocab_size * d          # token embedding
         n += self.vocab_size * d if not self.tie_embeddings else 0  # lm head
         for mixer, mlp in self.layer_kinds():
-            n += 2 * d                        # two RMSNorm scales
+            n += d * ((mixer != MIXER_NONE) + (mlp != MLP_NONE))  # RMSNorms
             if mixer in (ATTN, LOCAL_ATTN, CROSS_ATTN):
                 hd = self.head_dim
                 n += d * self.num_heads * hd               # q
@@ -153,7 +160,7 @@ class ModelConfig:
                 n += 2 * w * w // 1            # lru input/recurrent gates (block-diag approx -> dense here)
                 n += w                         # Lambda param
                 n += w * d                     # out proj
-            mats = 2 if self.mlp_gelu else 3   # gelu: up,down; swiglu: gate,up,down
+            mats = 3 if self.mlp_act == "swiglu" else 2   # gate?, up, down
             if mlp == MLP_DENSE:
                 n += mats * d * self.d_ff
             elif mlp == MLP_MOE:
@@ -167,7 +174,7 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         n = self.param_count()
-        mats = 2 if self.mlp_gelu else 3
+        mats = 3 if self.mlp_act == "swiglu" else 2
         per_layer_moe = self.num_experts * mats * self.d_model * self.d_ff
         active = self.top_k * mats * self.d_model * self.d_ff
         n_moe_layers = sum(1 for _, m in self.layer_kinds() if m == MLP_MOE)
@@ -246,8 +253,15 @@ def smoke_config(name: str) -> ModelConfig:
     if cfg.name == "minicpm3-4b":
         small.update(q_lora_rank=32, kv_lora_rank=16, qk_rope_dim=8,
                      qk_nope_dim=8, v_head_dim=16)
-    if cfg.family == "ssm":
-        small.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
+    if len(cfg.pattern) > 8:
+        # a long unperiodic stack: one layer of each kind, in the order
+        # they first appear
+        small.update(pattern=tuple(dict.fromkeys(cfg.pattern)),
+                     first_layer=0)
+        small["num_layers"] = len(small["pattern"])
+    if cfg.ssm_state:
+        small.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32,
+                     ssm_ngroups=min(cfg.ssm_ngroups, 2))
     if cfg.family == "hybrid":
         small.update(lru_width=64, window=32)
     if cfg.window and cfg.family != "hybrid":

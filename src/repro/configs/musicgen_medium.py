@@ -19,6 +19,6 @@ def config() -> ModelConfig:
         d_ff=6144,
         vocab_size=2048,          # EnCodec codebook size
         external_embed=True,
-        mlp_gelu=True,            # classic transformer FFN
+        mlp_act="gelu",           # classic transformer FFN
         pattern=((ATTN, MLP_DENSE),),
     )

@@ -16,6 +16,6 @@ def config() -> ModelConfig:
         vocab_size=49152,
         rope_theta=1_000_000.0,
         qkv_bias=True,
-        mlp_gelu=True,            # starcoder2 uses a classic c_fc/c_proj GELU FFN
+        mlp_act="gelu",           # starcoder2 uses a classic c_fc/c_proj GELU FFN
         pattern=((ATTN, MLP_DENSE),),
     )

@@ -136,12 +136,15 @@ def _qkv(cfg: ModelConfig, p, x, kv_src):
 
 
 def roped_qkv(cfg: ModelConfig, p, x, positions):
-    """Project + (optional) qk-norm + rope at (b, s) `positions` — the
-    shared front half of every self-attention mode."""
+    """Project + (optional) qk-norm + rope at (b, s) `positions` (none
+    where the config has no position encoding) — the shared front half
+    of every self-attention mode."""
     q, k_new, v_new = _qkv(cfg, p, x, x)
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"])
         k_new = rms_norm(k_new, p["k_norm"])
+    if not cfg.rope:
+        return q, k_new, v_new
     return (apply_rope(q, positions, cfg.rope_theta),
             apply_rope(k_new, positions, cfg.rope_theta), v_new)
 

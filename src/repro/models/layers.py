@@ -39,28 +39,39 @@ def apply_rope(x, positions, theta: float):
 
 
 # ---------------------------------------------------------------------------
-# MLP (SwiGLU or classic GELU)
+# MLP (SwiGLU, or a classic 2-matmul FFN with GELU or squared ReLU)
 # ---------------------------------------------------------------------------
+def gated(cfg: ModelConfig) -> bool:
+    """Whether the FFN has a gate projection (SwiGLU)."""
+    return cfg.mlp_act == "swiglu"
+
+
+def ffn_act(cfg: ModelConfig, up, gate=None):
+    """The FFN's hidden activation of the up projection ``up`` (and the
+    gate projection, SwiGLU only)."""
+    if cfg.mlp_act == "swiglu":
+        return jax.nn.silu(gate) * up
+    if cfg.mlp_act == "gelu":
+        return jax.nn.gelu(up)
+    if cfg.mlp_act == "relu2":
+        return jnp.square(jax.nn.relu(up))
+    raise ValueError(f"mlp_act {cfg.mlp_act!r}")
+
+
 def mlp_spec(cfg: ModelConfig):
     d, f = cfg.d_model, cfg.d_ff
-    if cfg.mlp_gelu:
-        return {
-            "up": ParamSpec((d, f), ("embed", "ffn"), init="fan_in"),
-            "down": ParamSpec((f, d), ("ffn", "embed"), init="fan_in"),
-        }
-    return {
-        "gate": ParamSpec((d, f), ("embed", "ffn"), init="fan_in"),
+    s = {
         "up": ParamSpec((d, f), ("embed", "ffn"), init="fan_in"),
         "down": ParamSpec((f, d), ("ffn", "embed"), init="fan_in"),
     }
+    if gated(cfg):
+        s = {"gate": ParamSpec((d, f), ("embed", "ffn"), init="fan_in"), **s}
+    return s
 
 
 def mlp_apply(cfg: ModelConfig, p, x):
     from repro.sharding.partition import constrain
-    if cfg.mlp_gelu:
-        h = jax.nn.gelu(x @ p["up"])
-    else:
-        h = jax.nn.silu(x @ p["gate"]) * (x @ p["up"])
+    h = ffn_act(cfg, x @ p["up"], x @ p["gate"] if gated(cfg) else None)
     h = constrain(h, ("batch", "seq", "ffn"))
     return h @ p["down"]
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec
+from repro.models.layers import ffn_act, gated
 
 def moe_spec(cfg: ModelConfig):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
@@ -21,7 +22,7 @@ def moe_spec(cfg: ModelConfig):
         "up": ParamSpec((e, d, f), ("experts", "embed", "ffn"), init="fan_in"),
         "down": ParamSpec((e, f, d), ("experts", "ffn", "embed"), init="fan_in"),
     }
-    if not cfg.mlp_gelu:
+    if gated(cfg):
         s["gate"] = ParamSpec((e, d, f), ("experts", "embed", "ffn"),
                               init="fan_in")
     return s
@@ -76,10 +77,8 @@ def moe_apply(cfg: ModelConfig, p, x):
     buckets = constrain(buckets, ("batch", "experts", "capacity", None))
 
     up = jnp.einsum("becd,edf->becf", buckets, p["up"])
-    if cfg.mlp_gelu:
-        h = jax.nn.gelu(up)
-    else:
-        h = jax.nn.silu(jnp.einsum("becd,edf->becf", buckets, p["gate"])) * up
+    h = ffn_act(cfg, up, jnp.einsum("becd,edf->becf", buckets, p["gate"])
+                if gated(cfg) else None)
     h = constrain(h, ("batch", "experts", "capacity", "ffn"))
     out_b = jnp.einsum("becf,efd->becd", h, p["down"])        # (b, e, cap, d)
     out_b = constrain(out_b, ("batch", "experts", "capacity", None))

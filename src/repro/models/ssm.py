@@ -10,7 +10,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec
-from repro.models.layers import rms_norm
 
 
 def ssm_dims(cfg: ModelConfig):
@@ -133,14 +132,102 @@ def _conv1d(xbc, w, bias):
     return out + bias
 
 
-def ssd_decode_core(cfg: ModelConfig, p, x, conv, state, *, tp: int = 1):
+def group_rms_norm(x, scale, groups: int, eps: float = 1e-6):
+    """RMSNorm taken over each of ``groups`` equal blocks of the last
+    axis (Mamba-2's gated norm with ``ngroups`` groups); one group is
+    `rms_norm`."""
+    shape = x.shape
+    x32 = x.astype(jnp.float32).reshape(shape[:-1]
+                                        + (groups, shape[-1] // groups))
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    y = (x32 * jax.lax.rsqrt(var + eps)).reshape(shape)
+    return (y * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
+
+
+def _gated_out(cfg: ModelConfig, p, y, z, dtype, tp: int):
+    """``y * silu(z)``, the gate norm per group, the out projection.
+    y, z: this shard's channels. Under ``tp > 1`` the groups either lie
+    whole on each shard (``ngroups % tp == 0``: a local norm) or there is
+    one group over all shards (one psum completes its mean square); the
+    row-sharded out projection ends in a psum."""
+    din, _, _ = ssm_dims(cfg)
+    g = cfg.ssm_ngroups
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    if tp > 1 and g % tp:
+        # one group over the FULL din: one psum completes the mean square
+        y32 = y.astype(dtype).astype(jnp.float32)
+        var = jax.lax.psum(jnp.sum(y32 * y32, axis=-1, keepdims=True),
+                           "model") / din
+        y = y32 * jax.lax.rsqrt(var + 1e-6)
+        y = (y * (1.0 + p["gate_norm"].astype(jnp.float32))).astype(dtype)
+    else:
+        y = group_rms_norm(y.astype(dtype), p["gate_norm"], g // tp)
+    out = y @ p["out_proj"]
+    return jax.lax.psum(out, "model") if tp > 1 else out
+
+
+def _heads(cfg: ModelConfig, p, xbc, dt_raw, tp: int):
+    """This shard's heads of the post-conv channels ``xbc`` (B, S, C) and
+    raw ``dt`` (B, S, H): ``(x (B,S,H_l,P), B and C (B,S,G_l,N) f32 of
+    the G_l groups those heads read, dt (B,S,H_l) f32 after softplus,
+    A (H_l,), first head)``. Each group is shared by H_l / G_l
+    consecutive heads."""
+    din, nh, _ = ssm_dims(cfg)
+    g, n, P = cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_head_dim
+    Bsz, S = xbc.shape[0], xbc.shape[1]
+    nh_l = p["a_log"].shape[0]            # local heads ("ssm_heads" shard)
+    per = nh // g                         # heads a group serves
+    g_l = max(1, nh_l // per)             # groups this shard's heads read
+    h0 = jax.lax.axis_index("model") * nh_l if tp > 1 else 0
+
+    def local(a, start, size):
+        return a if tp == 1 else \
+            jax.lax.dynamic_slice_in_dim(a, start, size, axis=2)
+
+    dt = jax.nn.softplus(local(dt_raw, h0, nh_l).astype(jnp.float32)
+                         + p["dt_bias"])
+    xs = local(xbc[..., :din].reshape(Bsz, S, nh, P), h0, nh_l)
+    bm = xbc[..., din:din + g * n].reshape(Bsz, S, g, n)
+    cm = xbc[..., din + g * n:].reshape(Bsz, S, g, n)
+    bm = local(bm.astype(jnp.float32), h0 // per, g_l)
+    cm = local(cm.astype(jnp.float32), h0 // per, g_l)
+    return xs, bm, cm, dt, -jnp.exp(p["a_log"]), h0
+
+
+def _local_z(cfg: ModelConfig, z, h0, nh_l: int, tp: int):
+    if tp == 1:
+        return z
+    P = cfg.ssm_head_dim
+    return jax.lax.dynamic_slice_in_dim(z, h0 * P, nh_l * P, axis=2)
+
+
+def vmap_rows(state, step, inputs):
+    """The cores' default per-row map: ``step(S_i, inputs_i) -> (out_i,
+    S_i')`` vmapped over a (B, H, P, N) ``state``. Returns (outs, new
+    state)."""
+    return jax.vmap(step)(state, inputs)
+
+
+def _decode_state_step(s, inp):
+    """One row, one token: S' = exp(dt A) S + dt x B^T; y = S' C."""
+    da, dt, b, x, c = inp
+    dbx = dt[:, None, None] * b[:, None, :] * x[:, :, None]    # (H,P,N)
+    s = s * da[..., None, None] + dbx
+    return jnp.einsum("hpn,hn->hp", s, c), s
+
+
+def ssd_decode_core(cfg: ModelConfig, p, x, conv, state, *, tp: int = 1,
+                    map_rows=vmap_rows):
     """One-token SSD step shared by the dense decode-cache path and the
     serve layer's fused paged step (the serving hot path traces this
     inside its jitted graph, so dense decode and fused serving agree by
     construction).
 
     x: (B, 1, d); conv: (B, K-1, conv_dim) raw pre-conv inputs; state:
-    (B, H, P, N) fp32. Returns ``(y (B, 1, d), new_conv, new_state)``.
+    what ``map_rows(state, step, inputs) -> (outs, new state)`` advances
+    row by row: a (B, H, P, N) fp32 array under `vmap_rows`, or the serve
+    layer's store under its in-place row loop. Returns ``(y (B, 1, d),
+    new_conv, new_state)``.
 
     ``tp > 1`` is the tensor-parallel form, valid only inside a shard_map
     body with a "model" axis: the in-projection and conv run replicated at
@@ -148,13 +235,9 @@ def ssd_decode_core(cfg: ModelConfig, p, x, conv, state, *, tp: int = 1):
     channels are group-shared and cannot split by head), the head block
     local to this shard is sliced out (state stays head-sharded, like
     attention heads), and the gate norm / out projection complete their
-    full-width reductions with one psum each.
+    reductions as `_gated_out` says.
     """
-    din, nh, conv_dim = ssm_dims(cfg)
-    g, n = cfg.ssm_ngroups, cfg.ssm_state
-    P = cfg.ssm_head_dim
     B = x.shape[0]
-
     from repro.sharding.partition import constrain
     proj = constrain(x @ p["in_proj"], ("batch", "seq", "ssm_inner"))
     z, xbc, dt_raw = _split_proj(cfg, proj)
@@ -163,61 +246,87 @@ def ssd_decode_core(cfg: ModelConfig, p, x, conv, state, *, tp: int = 1):
     xbc_t = jax.nn.silu(xbc_t)[:, None, :]
     new_conv = window[:, 1:, :]
 
-    if tp == 1:
-        a = -jnp.exp(p["a_log"])
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
-        xs = xbc_t[..., :din].reshape(B, 1, nh, P)
-        bm = xbc_t[..., din:din + g * n].reshape(B, 1, g, n)
-        cm = xbc_t[..., din + g * n:].reshape(B, 1, g, n)
-        da = jnp.exp(dt[:, 0, :] * a)                 # (B,H)
-        # broadcast groups to heads
-        bm_h = jnp.repeat(bm[:, 0], nh // g, axis=1).astype(jnp.float32)
-        cm_h = jnp.repeat(cm[:, 0], nh // g, axis=1).astype(jnp.float32)
-        dbx = dt[:, 0, :, None, None] * bm_h[:, :, None, :] * \
-            xs[:, 0, :, :, None].astype(jnp.float32)  # (B,H,P,N)
-        new_state = state * da[..., None, None] + dbx
-        y = jnp.einsum("bhpn,bhn->bhp", new_state, cm_h)
-        y = y + p["d_skip"][None, :, None] * xs[:, 0].astype(jnp.float32)
-        y = y.reshape(B, 1, din)
-        y = y * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y.astype(x.dtype), p["gate_norm"])
-        return y @ p["out_proj"], new_conv, new_state
+    xs, bm, cm, dt, a, h0 = _heads(cfg, p, xbc_t, dt_raw, tp)
+    nh_l = a.shape[0]
+    rep = nh_l // bm.shape[2]
+    # broadcast groups to heads
+    bm_h = jnp.repeat(bm[:, 0], rep, axis=1)          # (B,H,N)
+    cm_h = jnp.repeat(cm[:, 0], rep, axis=1)
+    da = jnp.exp(dt[:, 0, :] * a)                     # (B,H)
+    x32 = xs[:, 0].astype(jnp.float32)
+    y, new_state = map_rows(state, _decode_state_step,
+                            (da, dt[:, 0], bm_h, x32, cm_h))
+    y = y + p["d_skip"][None, :, None] * x32
+    y = y.reshape(B, 1, nh_l * cfg.ssm_head_dim)
+    out = _gated_out(cfg, p, y, _local_z(cfg, z, h0, nh_l, tp), x.dtype, tp)
+    return out, new_conv, new_state
 
-    # -- tensor-parallel form (shard_map body, "model" axis) ----------------
-    nh_l = p["a_log"].shape[0]            # local heads ("ssm_heads" shard)
-    din_l = nh_l * P
-    h0 = jax.lax.axis_index("model") * nh_l
-    d0 = h0 * P
-    a = -jnp.exp(p["a_log"])
-    dt_l = jax.lax.dynamic_slice_in_dim(dt_raw, h0, nh_l, axis=2)
-    dt = jax.nn.softplus(dt_l.astype(jnp.float32) + p["dt_bias"])
-    xs_full = xbc_t[..., :din].reshape(B, 1, nh, P)
-    xs = jax.lax.dynamic_slice_in_dim(xs_full, h0, nh_l, axis=2)
-    bm = xbc_t[..., din:din + g * n].reshape(B, 1, g, n)
-    cm = xbc_t[..., din + g * n:].reshape(B, 1, g, n)
-    bm_h = jax.lax.dynamic_slice_in_dim(
-        jnp.repeat(bm[:, 0], nh // g, axis=1).astype(jnp.float32),
-        h0, nh_l, axis=1)
-    cm_h = jax.lax.dynamic_slice_in_dim(
-        jnp.repeat(cm[:, 0], nh // g, axis=1).astype(jnp.float32),
-        h0, nh_l, axis=1)
-    da = jnp.exp(dt[:, 0, :] * a)
-    dbx = dt[:, 0, :, None, None] * bm_h[:, :, None, :] * \
-        xs[:, 0, :, :, None].astype(jnp.float32)
-    new_state = state * da[..., None, None] + dbx     # (B, nh_l, P, N)
-    y = jnp.einsum("bhpn,bhn->bhp", new_state, cm_h)
-    y = y + p["d_skip"][None, :, None] * xs[:, 0].astype(jnp.float32)
-    y = y.reshape(B, 1, din_l)
-    z_l = jax.lax.dynamic_slice_in_dim(z, d0, din_l, axis=2)
-    y = y * jax.nn.silu(z_l.astype(jnp.float32))
-    # gate rms_norm over the FULL din: one psum completes the mean square
-    y32 = y.astype(x.dtype).astype(jnp.float32)
-    var = jax.lax.psum(jnp.sum(y32 * y32, axis=-1, keepdims=True),
-                       "model") / din
-    y = y32 * jax.lax.rsqrt(var + 1e-6)
-    y = (y * (1.0 + p["gate_norm"].astype(jnp.float32))).astype(x.dtype)
-    out = y @ p["out_proj"]               # row-sharded -> partial sum
-    return jax.lax.psum(out, "model"), new_conv, new_state
+
+def _chunk_state_step(s, inp):
+    """One row, one chunk, from the state it starts at: y_i gains
+    exp(cum_i) C_i . S, and S' = exp(cum_k) S + U (U: the chunk's own
+    contribution)."""
+    c, cum, last, xw, b = inp      # (k,G,N) (k,G,R) (G,R) (k,G,R,P) (k,G,N)
+    g, r = last.shape
+    s5 = s.reshape((g, r) + s.shape[1:])
+    y = jnp.einsum("ign,grpn->igrp", c, s5) * jnp.exp(cum)[..., None]
+    s5 = s5 * jnp.exp(last)[..., None, None] \
+        + jnp.einsum("jgrp,jgn->grpn", xw, b)
+    return y, s5.reshape(s.shape)
+
+
+def ssd_chunk_core(cfg: ModelConfig, p, x, conv, state, n, *, tp: int = 1,
+                   map_rows=vmap_rows):
+    """The serve layer's k-token SSD step (a prompt chunk, with decode
+    rows riding it): row i feeds the first ``n[i]`` of its k tokens; the
+    rest are padding, whose dt is 0 (the state stays as it was) and which
+    never enter the conv window. The chunk is computed in its matmul
+    (dual) form from the state it starts at, which is read once and
+    written once: no per-token state is ever formed.
+
+    x: (B, k, d); conv: (B, K-1, conv_dim); state and ``map_rows`` as in
+    `ssd_decode_core`; n: (B,) int32 in [1, k]. Returns ``(y (B, k, d),
+    conv and state after n[i] tokens)``. ``tp`` as in
+    `ssd_decode_core`."""
+    K, P = cfg.ssm_conv_width, cfg.ssm_head_dim
+    B, k = x.shape[0], x.shape[1]
+    from repro.sharding.partition import constrain
+    proj = constrain(x @ p["in_proj"], ("batch", "seq", "ssm_inner"))
+    z, xbc, dt_raw = _split_proj(cfg, proj)
+    window = jnp.concatenate([conv.astype(xbc.dtype), xbc], axis=1)
+    taps = jnp.stack([window[:, i:i + k] for i in range(K)], axis=2)
+    xbc_t = jnp.einsum("bjkc,kc->bjc", taps, p["conv_w"]) + p["conv_b"]
+    xbc_t = jax.nn.silu(xbc_t)                         # (B, k, C)
+    last_taps = n[:, None] + jnp.arange(K - 1, dtype=n.dtype)[None, :]
+    new_conv = jnp.take_along_axis(window, last_taps[:, :, None], axis=1)
+
+    xs, bm, cm, dt, a, h0 = _heads(cfg, p, xbc_t, dt_raw, tp)
+    nh_l, g_l = a.shape[0], bm.shape[2]
+    r = nh_l // g_l                                    # heads per group
+    live = jnp.arange(k)[None, :] < n[:, None]         # (B, k)
+    dt = jnp.where(live[..., None], dt, 0.0)           # (B, k, H)
+    cum = jnp.cumsum(dt * a, axis=1)                   # (B, k, H)
+    # heads as (group, head within it), so B and C stay per group
+    x5 = xs.astype(jnp.float32).reshape(B, k, g_l, r, P)
+    dt5, cum5 = dt.reshape(B, k, g_l, r), cum.reshape(B, k, g_l, r)
+    # within the chunk: y_i = sum_{j<=i} (C_i.B_j) exp(cum_i - cum_j) dt_j x_j
+    ii, jj = jnp.arange(k)[:, None], jnp.arange(k)[None, :]
+    diff = cum5.transpose(0, 2, 3, 1)[..., :, None] \
+        - cum5.transpose(0, 2, 3, 1)[..., None, :]     # (B, G, R, i, j)
+    decay = jnp.exp(jnp.where(ii >= jj, diff, -jnp.inf))
+    scores = jnp.einsum("bign,bjgn->bgij", cm, bm)[:, :, None] * decay \
+        * dt5.transpose(0, 2, 3, 1)[..., None, :]
+    y = jnp.einsum("bgrij,bjgrp->bigrp", scores, x5)
+    # the state's part, and the state after the chunk: exp(cum_k) S +
+    # sum_j exp(cum_k - cum_j) dt_j x_j B_j^T (padding adds nothing)
+    last = cum5[:, -1]                                 # (B, G, R)
+    w = jnp.exp(last[:, None] - cum5) * dt5            # (B, k, G, R)
+    y_s, new_state = map_rows(state, _chunk_state_step,
+                              (cm, cum5, last, x5 * w[..., None], bm))
+    y = y + y_s + p["d_skip"].reshape(g_l, r)[None, None, :, :, None] * x5
+    y = y.reshape(B, k, nh_l * P)
+    out = _gated_out(cfg, p, y, _local_z(cfg, z, h0, nh_l, tp), x.dtype, tp)
+    return out, new_conv, new_state
 
 
 def ssm_apply(cfg: ModelConfig, p, x, *, mode: str, cache=None):
@@ -254,5 +363,5 @@ def ssm_apply(cfg: ModelConfig, p, x, *, mode: str, cache=None):
         new_cache = None
 
     y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(y.astype(x.dtype), p["gate_norm"])
+    y = group_rms_norm(y.astype(x.dtype), p["gate_norm"], cfg.ssm_ngroups)
     return y @ p["out_proj"], new_cache

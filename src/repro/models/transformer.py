@@ -12,8 +12,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import (ATTN, CROSS_ATTN, LOCAL_ATTN, MLA, MLP_DENSE,
-                                MLP_MOE, MLP_NONE, RGLRU, SSD, ModelConfig)
+from repro.configs.base import (ATTN, CROSS_ATTN, LOCAL_ATTN, MIXER_NONE, MLA,
+                                MLP_DENSE, MLP_MOE, MLP_NONE, RGLRU, SSD,
+                                ModelConfig)
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -29,7 +30,7 @@ from repro.sharding.partition import constrain
 # ---------------------------------------------------------------------------
 def layer_spec(cfg: ModelConfig, mixer: str, mlp: str):
     d = cfg.d_model
-    s = {"norm1": norm_spec(d)}
+    s = {} if mixer == MIXER_NONE else {"norm1": norm_spec(d)}
     if mixer in (ATTN, LOCAL_ATTN):
         s["attn"] = attn.attn_spec(cfg)
     elif mixer == CROSS_ATTN:
@@ -40,7 +41,7 @@ def layer_spec(cfg: ModelConfig, mixer: str, mlp: str):
         s["ssm"] = ssm_mod.ssm_spec(cfg)
     elif mixer == RGLRU:
         s["rglru"] = rglru_mod.rglru_spec(cfg)
-    else:
+    elif mixer != MIXER_NONE:
         raise ValueError(mixer)
     if mlp == MLP_DENSE:
         s["norm2"] = norm_spec(d)
@@ -71,8 +72,12 @@ def mlp_tail(cfg: ModelConfig, kind, p, x):
 
 def layer_apply(cfg: ModelConfig, kind, p, x, *, mode, positions=None,
                 cache=None, cross_embeds=None):
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux). A layer with no mixer has an empty
+    cache ({}) wherever the others have one."""
     mixer, mlp = kind
+    if mixer == MIXER_NONE:
+        x, aux = mlp_tail(cfg, kind, p, x)
+        return x, ({} if mode in ("prefill", "decode") else None), aux
     h = rms_norm(x, p["norm1"])
     if mixer in (ATTN, LOCAL_ATTN, CROSS_ATTN):
         window = cfg.window if mixer == LOCAL_ATTN else 0
@@ -105,6 +110,8 @@ def layer_cache_spec(cfg: ModelConfig, kind, batch: int, capacity: int):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
 
+    if mixer == MIXER_NONE:
+        return {}, {}
     if mixer == ATTN:
         shp = (batch, capacity, hkv, hd)
         log = ("batch", "kv_seq", "kv_heads", "head_dim")

@@ -231,13 +231,13 @@ class ServeEngine:
                             layout=self._layout())
 
     def _fused_step_fn(self, slots: int, greedy: bool, temperature: float,
-                       k: int = 1):
-        key = (slots, greedy, float(temperature), k)
+                       k: int = 1, drafts: bool = True):
+        key = (slots, greedy, float(temperature), k, drafts)
         fn = self._fused_cache.get(key)
         if fn is None:
             fn = build_fused_step(self.model, slots, k=k, greedy=greedy,
                                   temperature=temperature, plan=self.plan,
-                                  layout=self._layout())
+                                  layout=self._layout(), drafts=drafts)
             self._fused_cache[key] = fn
         return fn
 
@@ -636,6 +636,15 @@ class _SessionRec:
         self.stats: Optional[dict] = None
 
 
+def _free_device_bytes():
+    """Bytes the default device reports free, or None where it reports
+    no memory statistics (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
 class ServeSession:
     """Resumable, step-granular continuous-batching loop — the serving
     core that both `ServeEngine.serve` (closed batch) and the async
@@ -726,6 +735,9 @@ class ServeSession:
         self.state = engine._new_state(
             self.capacity, batch_hint=n_rows,
             tail_slots=2 if (k > 1 or self.chunked) else 1)
+        if k > 1:
+            self._check_checkpoints(max(k, self.pool.page_tokens)
+                                    if self.chunked else k, n_rows)
         # prefix-cache hit accounting (pages adopted / adoptable pages)
         self.pages_adopted_total = 0
         self.pages_needed_total = 0
@@ -764,6 +776,26 @@ class ServeSession:
             self._fault = (kind, float(p) if p else 1.0)
         self._fault_rng = np.random.default_rng(seed ^ 0x5EED)
         self._debug = bool(os.environ.get("REPRO_SERVE_DEBUG"))
+
+    def _check_checkpoints(self, k: int, rows: int):
+        """Speculative verify over recurrent layers holds k candidate
+        states of every recurrent layer for every row until the accept
+        rule picks one; refuse a session whose checkpoints cannot fit
+        the device memory left beside its weights and state."""
+        lay = self.engine._layout()
+        plan = self.engine.plan
+        need = k * rows * lay.rec_state_bytes()
+        if plan is not None:
+            need //= plan.dp * plan.tp
+        free = _free_device_bytes()
+        if need and free is not None and need > free:
+            raise ValueError(
+                f"{self.engine.cfg.name}: speculative verify {k} tokens "
+                f"wide over {rows} rows holds {need} bytes of recurrent-"
+                f"state checkpoints per device ({k} x "
+                f"{lay.rec_state_bytes()} bytes a row), more than the "
+                f"{free} bytes the device has free; serve it with "
+                f"speculate <= 1")
 
     # -- lifecycle ----------------------------------------------------------
     @property
@@ -1260,7 +1292,8 @@ class ServeSession:
                 # feedback is needed
                 k = max(self.spec_k, t) if wide else self.spec_k
                 step_fn = eng._fused_step_fn(state.slots, self.greedy,
-                                             self.temperature, k=k) \
+                                             self.temperature, k=k,
+                                             drafts=self.spec_k > 1) \
                     if wide else self._step_fn
                 budget = self.prefill_budget
                 srows: list[Optional[dict]] = []

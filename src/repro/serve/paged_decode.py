@@ -53,8 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import (ATTN, LOCAL_ATTN, MLP_DENSE, MLP_MOE,
-                                MLP_NONE, RGLRU, SSD)
+from repro.configs.base import (ATTN, LOCAL_ATTN, MIXER_NONE, MLP_DENSE,
+                                MLP_MOE, MLP_NONE, RGLRU, SSD)
 from repro.kernels import api
 from repro.models.attention import decode_qkv
 from repro.models.layers import lm_head_apply, rms_norm
@@ -64,8 +64,8 @@ from repro.serve.device_pool import DevicePagePool
 from repro.serve.kvcache import PagedKVPool
 from repro.serve.paged_state import (RecurrentStore, StateLayout,
                                      gather_ring_kv, rec_array_names,
-                                     rec_array_specs, rec_gather,
-                                     rec_scan_tokens, rec_scatter,
+                                     rec_advance, rec_array_specs, rec_read,
+                                     rec_scan_tokens,
                                      ring_attend, select_checkpoint,
                                      supports_paged_layout)
 
@@ -337,28 +337,20 @@ class PagedKVState:
             self._spill_slot[seq] = slot
         return slot
 
-    def _ensure_rec_slot(self, seq: int) -> int:
-        """The sequence's O(1) recurrent slot (one state block per
-        recurrent layer), zero-initialized on first use."""
-        slot = self._rec_slot.get(seq)
-        if slot is None:
-            slot = self._rec.alloc(self.shard_of(seq))
-            self._rec.zero_slot(slot)
-            self._rec_slot[seq] = slot
-        return slot
-
     def write_prefill_rec(self, seq: int, blocks: dict):
         """Install post-prefill recurrent state for `seq`: ``blocks`` maps
-        store array names to (n_layers_of_kind, ...) host blocks. A full
-        block set skips the zero-init write (swap-in restores all names
-        bit-identically)."""
+        every store array name to (n_layers_of_kind, ...) host blocks
+        (a dense prefill's state, or a swap-in's, bit-identical)."""
         slot = self._rec_slot.get(seq)
         if slot is None:
-            slot = self._rec.alloc(self.shard_of(seq))
-            self._rec_slot[seq] = slot
-            if set(blocks) != set(self._rec.names):
-                self._rec.zero_slot(slot)
+            slot = self._rec_slot[seq] = self._rec.alloc(self.shard_of(seq))
         self._rec.write_slot(slot, blocks)
+
+    def _release_rec_slot(self, seq: int):
+        slot = self._rec_slot.pop(seq, None)
+        if slot is not None:
+            with tracing.span("serve.rec_store"):
+                self._rec.release_slot(slot)
 
     # -- per-step protocol ---------------------------------------------------
     def _build_row(self, seq: int) -> _TableRow:
@@ -405,7 +397,11 @@ class PagedKVState:
         rewrites), and copy each row's cached page-table row into the
         layer-uniform control block the fused step consumes. The span
         ``serve.begin_step`` counts the live ``rows`` and those
-        ``rebuilt`` from the pool this step (admission, swap-in).
+        ``rebuilt`` from the pool this step (admission, swap-in). A stack
+        with recurrent layers gives each row its state slot inside the
+        child span ``serve.rec_store``, whose count ``fresh`` is the rows
+        whose slot was allocated this step: the step reads zeros for them
+        (the control block's ``fresh`` column), so nothing is uploaded.
 
         ``k == 1`` (plain decode): ``(b, slots + 4)`` int32 rows
         ``[page table | tail slot | tail row | position | kv length]``,
@@ -462,8 +458,8 @@ class PagedKVState:
             control[:, c_len] = 1
             if self._rec is not None:
                 # dead rows read/write the recurrent trash slot, and keep
-                # exactly 1 phantom token (keep_cap 0) so their garbage never
-                # escapes the trash row
+                # exactly 1 phantom token (keep_fixed 1) so their garbage
+                # never escapes the trash row
                 control[:, cc.rec] = [self._rec.local_slot(self._rec.trash[sh])
                                       for sh in row_shard]
                 if k > 1:
@@ -512,6 +508,20 @@ class PagedKVState:
                         row.slots[j] = dev.local_slot(
                             dev.slot(int(row.pids[j, 0]), row_shard[i]))
                     row.synced = row.n
+            if self._rec is not None:
+                # a row's first step: its slot is allocated here and the
+                # step reads zeros for it (``fresh``) — nothing is uploaded
+                with tracing.span("serve.rec_store") as rs:
+                    fresh = 0
+                    for i, seq, _row in live:
+                        slot = self._rec_slot.get(seq)
+                        if slot is None:
+                            slot = self._rec_slot[seq] = \
+                                self._rec.alloc(self.shard_of(seq))
+                            control[i, cc.fresh] = 1
+                            fresh += 1
+                        control[i, cc.rec] = self._rec.local_slot(slot)
+                    rs.set(fresh=fresh)
             for i, seq, row in live:
                 n = row.n if row is not None else 0
                 tail = self.tail_len.get(seq, 0)
@@ -524,14 +534,11 @@ class PagedKVState:
                         control[i, s + 1] = \
                             dev.local_slot(self._ensure_spill_slot(seq))
                         control[i, n + 1] = control[i, s + 1]
-                if self._rec is not None:
-                    control[i, cc.rec] = \
-                        self._rec.local_slot(self._ensure_rec_slot(seq))
-                    if k > 1:
-                        control[i, cc.keep_fixed] = \
-                            -1 if keep_fixed is None else int(keep_fixed[i])
-                        control[i, cc.keep_cap] = \
-                            k - 1 if keep_cap is None else int(keep_cap[i])
+                if self._rec is not None and k > 1:
+                    control[i, cc.keep_fixed] = \
+                        -1 if keep_fixed is None else int(keep_fixed[i])
+                    control[i, cc.keep_cap] = \
+                        k - 1 if keep_cap is None else int(keep_cap[i])
                 if cc is not None and lay.has_ring:
                     control[i, cc.base] = self._ring_base.get(seq, 0)
                 control[i, c_row] = tail
@@ -818,11 +825,11 @@ class PagedKVState:
         else:
             self._parked_tail[seq] = None   # numpy tails already host-side
         if self._rec is not None:
-            slot = self._rec_slot.pop(seq, None)
+            slot = self._rec_slot.get(seq)
             if slot is not None:
                 blocks = self._rec.read_slot(slot)
                 self._parked_rec[seq] = blocks
-                self._rec.release_slot(slot)
+                self._release_rec_slot(seq)
                 rec_bytes = sum(v.nbytes for v in blocks.values())
                 self.pool.stats["swap_out_bytes"] += rec_bytes
                 tail_bytes += rec_bytes
@@ -875,9 +882,7 @@ class PagedKVState:
         self._ring_base.pop(seq, None)
         self._table.pop(seq, None)
         if self._rec is not None:
-            slot = self._rec_slot.pop(seq, None)
-            if slot is not None:
-                self._rec.release_slot(slot)
+            self._release_rec_slot(seq)
         for key in [k for k in self.tail_data if k[0] == seq]:
             self.tail_data.pop(key)
         for slot in (self._tail_slot.pop(seq, None),
@@ -1007,6 +1012,8 @@ def extract_prefill_pages(model, caches, state: PagedKVState, seq_ids,
     rec_parts: list[dict] = [{} for _ in seq_ids]
 
     def emit(glayer, mixer, c, cut=None):
+        if mixer == MIXER_NONE:
+            return
         if mixer == SSD:
             names = (("ssd_conv", "conv"), ("ssd_state", "state"))
         elif mixer == RGLRU:
@@ -1148,7 +1155,8 @@ def _wrap_step(step, model, plan, *, k, control_spec, out_spec,
 
 def build_fused_step(model, num_slots: int, *, k: int = 1,
                      backend: str = "auto", greedy: bool = True,
-                     temperature: float = 1.0, plan=None, layout=None):
+                     temperature: float = 1.0, plan=None, layout=None,
+                     drafts: bool = True):
     """Build the jitted fused decode step.
 
     ``k == 1`` — the plain PR-4 step. Returned callable:
@@ -1193,8 +1201,18 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
     heterogeneous stacks: LOCAL_ATTN layers scatter into the same KV pool
     but attend a ring gather windowed by the control block's base column,
     SSD/RGLRU layers read/advance their O(1) state slot in the
-    RecurrentStore arrays riding behind the six pool arrays. Pure-ATTN
-    stacks trace the identical legacy graph with or without a layout."""
+    RecurrentStore arrays riding behind the six pool arrays (zeros in its
+    place at a row's first step, the control block's ``fresh`` column).
+    Pure-ATTN stacks trace the identical legacy graph with or without a
+    layout.
+
+    ``drafts`` (k > 1): whether rows may carry draft tokens, whose kept
+    count the accept rule decides inside the graph — then each recurrent
+    layer emits its k candidate states for the checkpoint commit. A wide
+    step without drafts (prompt chunks, decode rows riding them) knows
+    every row's count up front (``keep_fixed``): each recurrent layer
+    reads and writes its slots once, an SSD layer through its chunk
+    form."""
     cfg = model.cfg
     gs = len(model.group_kinds)
     s = num_slots
@@ -1206,7 +1224,7 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
     if k > 1:
         return _build_spec_step(model, num_slots, k, backend=backend,
                                 greedy=greedy, temperature=temperature,
-                                plan=plan, layout=lay)
+                                plan=plan, layout=lay, drafts=drafts)
     cc = lay.cols(s, 1)
     rec_of = {n: i for i, n in enumerate(rec_array_names(lay))}
     n_rec = len(rec_of)
@@ -1232,6 +1250,7 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
         # flat (layer, slot, row) scatter index base for the step's rows
         row_base = control[:, cc.tail] * t + control[:, cc.row]
         rec_slots = control[:, cc.rec] if lay.has_rec else None
+        fresh = control[:, cc.fresh] if lay.has_rec else None
         ring_base = control[:, cc.base] if lay.has_ring else None
         flat_kv = (ll * c * t,) + kf.shape[3:]
 
@@ -1241,9 +1260,9 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
             x, kf, vf = carry[0], carry[1], carry[2]
             rec = list(carry[3:])
             mixer, _mlp = kind
-            with jax.named_scope(_mixer_scope(mixer)):
-                h = rms_norm(x, p["norm1"])
-                if mixer in (ATTN, LOCAL_ATTN):
+            if mixer in (ATTN, LOCAL_ATTN):
+                with jax.named_scope(_mixer_scope(mixer)):
+                    h = rms_norm(x, p["norm1"])
                     ap = p["attn"]
                     q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
                     idx = row_kv * (c * t) + row_base
@@ -1267,27 +1286,18 @@ def build_fused_step(model, num_slots: int, *, k: int = 1,
                     if tp > 1:      # complete the head-sharded partial sum
                         y = jax.lax.psum(y, "model")
                     x = x + y[:, None]
-                elif mixer == SSD:
-                    ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
-                    state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
-                              rec_gather(rec[ib], row_ssd, rec_slots))
-                    y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
-                                                tp=tp)
-                    rec[ia] = rec_scatter(rec[ia], row_ssd, rec_slots,
-                                          states[0][0])
-                    rec[ib] = rec_scatter(rec[ib], row_ssd, rec_slots,
-                                          states[1][0])
-                    x = x + y
-                else:               # RGLRU
-                    ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
-                    state0 = (rec_gather(rec[ia], row_rg, rec_slots),
-                              rec_gather(rec[ib], row_rg, rec_slots))
-                    y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
-                                                state0, tp=tp)
-                    rec[ia] = rec_scatter(rec[ia], row_rg, rec_slots,
-                                          states[0][0])
-                    rec[ib] = rec_scatter(rec[ib], row_rg, rec_slots,
-                                          states[1][0])
+            elif mixer != MIXER_NONE:   # SSD / RGLRU: the row's slot
+                with jax.named_scope(_mixer_scope(mixer)):
+                    h = rms_norm(x, p["norm1"])
+                    names, row = (("ssd_conv", "ssd_state"), row_ssd) \
+                        if mixer == SSD else (("rg_h", "rg_conv"), row_rg)
+                    ids = [rec_of[n] for n in names]
+                    y, new = rec_advance(
+                        cfg, mixer, p["ssm" if mixer == SSD else "rglru"], h,
+                        tuple(rec[i] for i in ids), row, rec_slots, fresh,
+                        None, tp=tp)
+                    for i, a in zip(ids, new):
+                        rec[i] = a
                     x = x + y
             with jax.named_scope("mlp"):
                 x = _mlp_tail_tp(cfg, kind, p, x, tp)
@@ -1393,16 +1403,16 @@ def _commit_rec_checkpoints(model, lay, rec, rec_of, group_states,
 
 def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
                      greedy: bool = True, temperature: float = 1.0,
-                     plan=None, layout=None):
+                     plan=None, layout=None, drafts: bool = True):
     """The k-row speculative verify graph behind `build_fused_step(k>1)`;
     see that docstring for the contract.
 
-    Recurrent layers verify by construction in O(1) per token: the
-    pre-step state slot is READ once, the scan emits all k candidate
-    post-token states as stacked outputs (never overwriting in-scan), and
-    after the accept rule resolves each row's ``keep`` count, ONE scatter
-    per store array commits checkpoint ``keep - 1``. Rollback is
-    selection, not replay."""
+    With ``drafts``, recurrent layers verify by construction in O(1) per
+    token: the pre-step state slot is READ once, the scan emits all k
+    candidate post-token states as stacked outputs (never overwriting
+    in-scan), and after the accept rule resolves each row's ``keep``
+    count, ONE scatter per store array commits checkpoint ``keep - 1``.
+    Rollback is selection, not replay."""
     cfg = model.cfg
     gs = len(model.group_kinds)
     s = num_slots
@@ -1432,9 +1442,14 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
         lengths = control[:, cc.len]                        # row 0's length
         tokens = control[:, cc.tok:cc.tok + k]              # (b, k)
         rec_slots = control[:, cc.rec] if lay.has_rec else None
+        fresh = control[:, cc.fresh] if lay.has_rec else None
         ring_base = control[:, cc.base] if lay.has_ring else None
         keeps = (control[:, cc.keep_fixed], control[:, cc.keep_cap]) \
             if lay.has_rec else None
+        # without drafts every row's token count is fixed before the step
+        # (a chunk row's chunk length, else 1: no row proposes drafts)
+        fixed = jnp.clip(jnp.where(keeps[0] >= 0, keeps[0], 1), 1, k) \
+            if lay.has_rec and not drafts else None
         offs = jnp.arange(k, dtype=jnp.int32)
         positions = pos0[:, None] + offs[None, :]           # (b, k)
         # per-row scatter target: rows crossing the page boundary go to
@@ -1446,14 +1461,19 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
 
         x = model._embed_in(params, {"tokens": tokens})     # (b, k, d)
 
-        def layer_step(x, kf, vf, kind, p, row_kv, row_ssd, row_rg):
-            """-> (x, kf, vf, states): `states` is None for KV/ring
-            layers, else the stacked (k, b, ...) candidate-state leaves
-            the post-accept checkpoint commit selects from."""
+        def layer_step(carry, kind, p, row_kv, row_ssd, row_rg):
+            """-> (carry, states): `states` is the stacked (k, b, ...)
+            candidate-state leaves of a recurrent layer that the
+            post-accept checkpoint commit selects from (``drafts``), else
+            None; without drafts a recurrent layer advances its slots in
+            place by each row's fixed token count."""
+            x, kf, vf = carry[0], carry[1], carry[2]
+            rec = list(carry[3:])
             mixer, _mlp = kind
-            with jax.named_scope(_mixer_scope(mixer)):
-                h = rms_norm(x, p["norm1"])
-                if mixer in (ATTN, LOCAL_ATTN):
+            states = None
+            if mixer in (ATTN, LOCAL_ATTN):
+                with jax.named_scope(_mixer_scope(mixer)):
+                    h = rms_norm(x, p["norm1"])
                     ap = p["attn"]
                     q, k_new, v_new = decode_qkv(cfg, ap, h, positions)
                     idx = (row_kv * (c * t) + row_base).reshape(-1)  # (b * k,)
@@ -1482,46 +1502,51 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
                     if tp > 1:      # complete the head-sharded partial sum
                         y = jax.lax.psum(y, "model")
                     x = x + y
-                    states = None
-                elif mixer == SSD:
-                    ia, ib = rec_of["ssd_conv"], rec_of["ssd_state"]
-                    state0 = (rec_gather(rec[ia], row_ssd, rec_slots),
-                              rec_gather(rec[ib], row_ssd, rec_slots))
-                    y, states = rec_scan_tokens(cfg, SSD, p["ssm"], h, state0,
-                                                tp=tp)
-                    x = x + y
-                else:               # RGLRU
-                    ia, ib = rec_of["rg_h"], rec_of["rg_conv"]
-                    state0 = (rec_gather(rec[ia], row_rg, rec_slots),
-                              rec_gather(rec[ib], row_rg, rec_slots))
-                    y, states = rec_scan_tokens(cfg, RGLRU, p["rglru"], h,
-                                                state0, tp=tp)
+            elif mixer != MIXER_NONE:   # SSD / RGLRU: the row's slot
+                with jax.named_scope(_mixer_scope(mixer)):
+                    h = rms_norm(x, p["norm1"])
+                    names, row = (("ssd_conv", "ssd_state"), row_ssd) \
+                        if mixer == SSD else (("rg_h", "rg_conv"), row_rg)
+                    ids = [rec_of[n] for n in names]
+                    pm = p["ssm" if mixer == SSD else "rglru"]
+                    if drafts:
+                        state0 = tuple(rec_read(rec[i], row, rec_slots, fresh)
+                                       for i in ids)
+                        y, states = rec_scan_tokens(cfg, mixer, pm, h, state0,
+                                                    tp=tp)
+                    else:
+                        y, new = rec_advance(cfg, mixer, pm, h,
+                                             tuple(rec[i] for i in ids), row,
+                                             rec_slots, fresh, fixed, tp=tp)
+                        for i, a in zip(ids, new):
+                            rec[i] = a
                     x = x + y
             with jax.named_scope("mlp"):
                 x = _mlp_tail_tp(cfg, kind, p, x, tp)
-            return x, kf, vf, states
+            return (x, kf, vf, *rec), states
 
         def group_body(carry, xs):
-            x, kf, vf = carry
             gp, g = xs
             ys = []
             for i, kind in enumerate(model.group_kinds):
-                x, kf, vf, st = layer_step(x, kf, vf, kind, gp[f"l{i}"],
-                                           *rows_of(g, i))
+                carry, st = layer_step(carry, kind, gp[f"l{i}"],
+                                       *rows_of(g, i))
                 if st is not None:
                     ys.append(st)
-            return (x, kf, vf), tuple(ys)
+            return carry, tuple(ys)
 
-        (x, kf, vf), group_states = jax.lax.scan(
-            group_body, (x, kf, vf),
+        carry, group_states = jax.lax.scan(
+            group_body, (x, kf, vf, *rec),
             (params["groups"], jnp.arange(model.n_groups)))
         tail_states = []
         for i, kind in enumerate(model.tail_kinds):
-            x, kf, vf, st = layer_step(
-                x, kf, vf, kind, params["tail"][f"t{i}"],
+            carry, st = layer_step(
+                carry, kind, params["tail"][f"t{i}"],
                 lay.tail_kv[i], lay.tail_ssd[i], lay.tail_rg[i])
             if st is not None:
                 tail_states.append(st)
+        x, kf, vf = carry[0], carry[1], carry[2]
+        rec = list(carry[3:])
 
         with jax.named_scope("logits"):
             x = rms_norm(x, params["final_norm"])
@@ -1541,7 +1566,7 @@ def _build_spec_step(model, num_slots: int, k: int, *, backend: str = "auto",
             n_acc = jnp.cumprod(match, axis=1).sum(axis=1)
             verdict = jnp.concatenate([samp, n_acc[:, None]], axis=1)
 
-        if lay.has_rec:
+        if lay.has_rec and drafts:
             # commit the per-row state checkpoint: chunked-prefill rows
             # keep their fixed token count, verify rows keep accepted +
             # bonus capped at the row's real proposal count — O(1)

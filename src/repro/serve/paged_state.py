@@ -19,8 +19,12 @@ layout off `ModelConfig.pattern` per layer:
           `RecurrentStore` sharing the device pool's slot discipline
           (per-shard free lists, trash slot for dead rows, host parking
           for preemption). O(1) per sequence regardless of length; the
-          fused step updates it in place via the single-token step forms
-          of `ssd_decode_core` / `rglru_decode_core`.
+          fused step updates it in place: one token through the
+          single-token forms of `ssd_decode_core` / `rglru_decode_core`,
+          a prompt chunk through `ssd_chunk_core` (SSD: the state is read
+          and written once a chunk) or an RG-LRU scan.
+
+``none``  layers with no token mixer (an MLP alone): no state.
 
 ``ring``  `LOCAL_ATTN` layers: a window-sized circular page set. Pages
           fill exactly like KV pages, but once ``pos >= window`` the
@@ -35,6 +39,10 @@ column layout, per-request page charge for the scheduler's admission
 math). `RecurrentStore` owns the recurrent device arrays. The
 ``*_fused_*`` functions are the jit-traceable step forms the fused
 decode graph (`serve.paged_decode.build_fused_step`) scans over.
+
+A row's state starts from zero on the device: the control block's
+``fresh`` column tells the fused step to read zeros instead of the slot
+at the row's first step, so admission uploads nothing.
 
 Speculative verify over recurrent layers checkpoints by construction:
 the pre-step state is *read* (never overwritten in-scan), the k
@@ -56,19 +64,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import (ATTN, CROSS_ATTN, LOCAL_ATTN, MLA, MLP_DENSE,
-                                MLP_MOE, MLP_NONE, RGLRU, SSD)
+from repro.configs.base import (ATTN, CROSS_ATTN, LOCAL_ATTN, MIXER_NONE, MLA,
+                                MLP_DENSE, MLP_MOE, MLP_NONE, RGLRU, SSD)
 from repro.models.rglru import rglru_decode_core
-from repro.models.ssm import ssd_decode_core, ssm_dims
+from repro.models.ssm import ssd_chunk_core, ssd_decode_core, ssm_dims
 
-KV, REC, RING = "kv", "rec", "ring"
+KV, REC, RING, STATELESS = "kv", "rec", "ring", "none"
 
 RGLRU_CONV_TAPS = 4          # Griffin's fixed temporal conv width
+TRASH_SLOT = 0               # each data shard's first recurrent slot: dead
+                             # rows' trash, whose content nothing reads
 
 
 def state_kind(mixer: str):
     """Which paged-state substrate a mixer's layer state lives on, or
     None for mixers the protocol does not cover (cross-attention)."""
+    if mixer == MIXER_NONE:
+        return STATELESS
     if mixer in (ATTN, MLA):
         return KV
     if mixer == LOCAL_ATTN:
@@ -76,13 +88,6 @@ def state_kind(mixer: str):
     if mixer in (SSD, RGLRU):
         return REC
     return None
-
-
-def _next_pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
 
 
 # ---------------------------------------------------------------------------
@@ -94,6 +99,8 @@ class ControlCols:
     stack with recurrent or ring layers appends columns at the end:
 
     ``rec``        this row's shard-local recurrent slot (has_rec)
+    ``fresh``      1 at a row's first step: its recurrent state starts
+                   from zero, not from the slot (has_rec)
     ``base``       dropped-ring-page count: table position n holds the
                    logical page ``base + n`` (has_ring)
     ``keep_fixed`` k > 1 only: fixed token-keep count for chunked
@@ -114,8 +121,8 @@ class ControlCols:
             self.tok = s + 5
             w = s + 5 + k
         if layout.has_rec:
-            self.rec = w
-            w += 1
+            self.rec, self.fresh = w, w + 1
+            w += 2
         if layout.has_ring:
             self.base = w
             w += 1
@@ -236,7 +243,7 @@ class StateLayout:
 
 def supports_paged_layout(cfg) -> bool:
     """Whether the paged-state protocol covers every layer of `cfg`:
-    ATTN / LOCAL_ATTN / SSD / RGLRU mixers with dense/MoE/none MLPs.
+    ATTN / LOCAL_ATTN / SSD / RGLRU / no mixers with dense/MoE/none MLPs.
     ATTN and LOCAL_ATTN cannot mix in one stack (the pool's page groups
     are layer-uniform, and ring recycling drops whole groups — a global
     layer would lose pages it still needs). MLA and cross-attention
@@ -247,7 +254,7 @@ def supports_paged_layout(cfg) -> bool:
         return False
     if mixers & {MLA, CROSS_ATTN}:
         return False
-    if not mixers <= {ATTN, LOCAL_ATTN, SSD, RGLRU}:
+    if not mixers <= {ATTN, LOCAL_ATTN, SSD, RGLRU, MIXER_NONE}:
         return False
     if ATTN in mixers and LOCAL_ATTN in mixers:
         return False
@@ -301,10 +308,15 @@ def _jit_rec_scatter():
     return jax.jit(lambda f, idx, v: f.at[idx].set(v), donate_argnums=(0,))
 
 
-def rec_gather(arr, idx, slots):
+def rec_read(arr, idx, slots, fresh):
     """(b, ...) state blocks at rows ``[idx, slots]`` of an (L, R, ...)
-    store array; `idx` may be traced (scan group index)."""
-    return _flat1(arr)[idx * arr.shape[1] + slots]
+    store array (`idx` may be traced: the scan group index), with zeros
+    for the rows whose state starts fresh (``fresh`` (b,) nonzero): a new
+    row's slot is never cleared by an upload, the step reads zeros in
+    its place."""
+    vals = _flat1(arr)[idx * arr.shape[1] + slots]
+    keep = (fresh == 0).reshape((-1,) + (1,) * (vals.ndim - 1))
+    return jnp.where(keep, vals, jnp.zeros((), vals.dtype))
 
 
 def rec_scatter(arr, idx, slots, vals):
@@ -327,6 +339,10 @@ class RecurrentStore:
     "data" and the state width over "model" (SSD heads / LRU width, like
     attention heads); conv taps replicate where the channel layout mixes
     head-local and group-shared channels.
+
+    The store is sized once, for ``batch_hint`` rows and a trash slot per
+    data shard, and never grows: a growth would copy every array (GBs at
+    published widths) and retrace the step.
     """
 
     _instances: "weakref.WeakSet[RecurrentStore]" = weakref.WeakSet()
@@ -339,7 +355,7 @@ class RecurrentStore:
         self.shards = plan.dp if plan is not None else 1
         tp = plan.tp if plan is not None else 1
         rows = -(-max(1, batch_hint) // self.shards)
-        self.slots_local = _next_pow2(max(8, rows + 1))
+        self.slots_local = rows + 1           # + the shard's trash slot
         self.slots = self.shards * self.slots_local
         self.names = list(rec_array_names(layout))
         shapes = {}
@@ -378,6 +394,7 @@ class RecurrentStore:
                       for s in range(self.shards)]
         self._used: set[int] = set()
         self.trash = [self.alloc(s) for s in range(self.shards)]
+        assert all(self.local_slot(t) == TRASH_SLOT for t in self.trash)
         self.writes = 0      # host->device scatter calls
         self.reads = 0       # device->host slot pulls
         RecurrentStore._instances.add(self)
@@ -393,23 +410,12 @@ class RecurrentStore:
         return slot // self.slots_local
 
     # -- slots ---------------------------------------------------------------
-    def _grow(self):
-        old = self.slots
-        self.slots *= 2
-        self.slots_local = self.slots
-        self.arrays = tuple(
-            jnp.pad(a, [(0, 0), (0, old)] + [(0, 0)] * (a.ndim - 2))
-            for a in self.arrays)
-        self._free[0].extend(range(self.slots - 1, old - 1, -1))
-
     def alloc(self, shard: int = 0) -> int:
         if not self._free[shard]:
-            if self.shards > 1:
-                raise RuntimeError(
-                    f"data shard {shard} exhausted its {self.slots_local} "
-                    f"recurrent slots — size batch_hint to the per-shard "
-                    f"worst case (sharded stores cannot grow)")
-            self._grow()
+            raise RuntimeError(
+                f"data shard {shard} exhausted its {self.slots_local} "
+                f"recurrent slots — size batch_hint to the per-shard "
+                f"worst case (the store never grows)")
         slot = self._free[shard].pop()
         self._used.add(slot)
         return slot
@@ -432,15 +438,14 @@ class RecurrentStore:
 
     def write_slot(self, slot: int, blocks: dict):
         """Host -> device: install per-layer state blocks at one slot.
-        ``blocks`` maps a subset of `names` to (L_kind, ...) arrays —
+        ``blocks`` maps every one of `names` to (L_kind, ...) arrays —
         prefill installation and swap-in both land here."""
+        if set(blocks) != set(self.names):
+            raise ValueError(f"a slot's state is written whole: got "
+                             f"{sorted(blocks)}, the store holds "
+                             f"{self.names}")
         for name, val in blocks.items():
             self._scatter_one(self.names.index(name), slot, val)
-
-    def zero_slot(self, slot: int):
-        self.write_slot(slot, {
-            n: np.zeros((a.shape[0],) + a.shape[2:], a.dtype)
-            for n, a in zip(self.names, self.arrays)})
 
     def read_slot(self, slot: int) -> dict:
         """Device -> host: every store's per-layer blocks at one slot
@@ -466,25 +471,85 @@ class RecurrentStore:
 # ---------------------------------------------------------------------------
 # Fused step forms (traced inside the jitted decode graph)
 # ---------------------------------------------------------------------------
+class StoreRows:
+    """The batch rows of one layer of a recurrent store array, as the
+    ``map_rows`` of the SSD cores: each row's block (zeros for a fresh
+    row) is read, advanced and written back in place, one row at a time,
+    so no batch of blocks is ever formed beside the store. A dead row
+    (the trash slot) is skipped: its output is zeros and no block
+    moves."""
+
+    def __init__(self, idx, slots, fresh):
+        self.slots, self.fresh = slots, fresh
+        self.idx = jnp.asarray(idx, jnp.int32)
+
+    def map(self, arr, step, inputs):
+        """(outs, new store array) of ``step(block, inputs_i) -> (out_i,
+        block')`` over the rows of ``arr``."""
+
+        def body(arr, xs):
+            slot, fresh, inp = xs
+            at = (self.idx, slot.astype(jnp.int32)) + (0,) * (arr.ndim - 2)
+
+            def advance(arr):
+                blk = jax.lax.dynamic_slice(arr, at,
+                                            (1, 1) + arr.shape[2:])[0, 0]
+                blk = jnp.where(fresh != 0, jnp.zeros((), blk.dtype), blk)
+                out, blk = step(blk, inp)
+                return jax.lax.dynamic_update_slice(
+                    arr, blk.astype(arr.dtype)[None, None], at), out
+
+            def skip(arr):
+                out = jax.eval_shape(lambda a: advance(a)[1], arr)
+                return arr, jax.tree.map(
+                    lambda o: jnp.zeros(o.shape, o.dtype), out)
+
+            return jax.lax.cond(slot == TRASH_SLOT, skip, advance, arr)
+
+        arr, outs = jax.lax.scan(body, arr, (self.slots, self.fresh, inputs))
+        return outs, arr
+
+
+def rec_advance(cfg, kind_mixer, p, x, arrays, idx, slots, fresh, n,
+                tp: int = 1):
+    """Advance one recurrent layer's rows over x: (b, k, d), row i by its
+    first ``n[i]`` tokens (the rest are padding), in the store arrays
+    ``arrays`` ((conv, state) for SSD, (h, conv) for RG-LRU) at layer row
+    ``idx``. Returns ``(y (b, k, d), new arrays)``. One token is the
+    single-token decode core; a wider step is SSD's chunk form or an
+    RG-LRU scan. The SSD state passes through `StoreRows` (read and
+    written once, in place); the small conv taps and RG-LRU state are
+    gathered and scattered."""
+    k = x.shape[1]
+    if kind_mixer == SSD:
+        conv0 = rec_read(arrays[0], idx, slots, fresh)
+        rows = StoreRows(idx, slots, fresh).map
+        if k == 1:
+            y, conv1, st = ssd_decode_core(cfg, p, x, conv0, arrays[1],
+                                           tp=tp, map_rows=rows)
+        else:
+            y, conv1, st = ssd_chunk_core(cfg, p, x, conv0, arrays[1], n,
+                                          tp=tp, map_rows=rows)
+        return y, (rec_scatter(arrays[0], idx, slots, conv1), st)
+    state0 = tuple(rec_read(a, idx, slots, fresh) for a in arrays)
+    if k == 1:
+        y, h1, conv1 = rglru_decode_core(cfg, p, x, *state0, tp=tp)
+        state1 = (h1, conv1)
+    else:
+        y, states = rec_scan_tokens(cfg, kind_mixer, p, x, state0, tp=tp)
+        state1 = tuple(select_checkpoint(s, n) for s in states)
+    return y, tuple(rec_scatter(a, idx, slots, v)
+                    for a, v in zip(arrays, state1))
+
+
 def rec_scan_tokens(cfg, kind_mixer, p, x, state0, tp: int = 1):
     """Run k single-token recurrent steps over x: (b, k, d) from the
     checkpoint ``state0`` (tuple of state leaves), emitting every
     intermediate state as a stacked output — the substrate of recurrent
     speculative verify: nothing is overwritten, so 'rollback' is
     selecting checkpoint ``keep - 1``. Returns
-    ``(y (b, k, d), states)`` where each states leaf is (k, b, ...).
-
-    Single-token callers (k == 1) get the exact decode-core graph."""
+    ``(y (b, k, d), states)`` where each states leaf is (k, b, ...)."""
     core = ssd_decode_core if kind_mixer == SSD else rglru_decode_core
-    k = x.shape[1]
-    if k == 1:
-        if kind_mixer == SSD:
-            conv, st = state0
-            y, conv1, st1 = core(cfg, p, x, conv, st, tp=tp)
-            return y, (conv1[None], st1[None])
-        h, conv = state0
-        y, h1, conv1 = core(cfg, p, x, h, conv, tp=tp)
-        return y, (h1[None], conv1[None])
 
     def body(carry, xj):
         if kind_mixer == SSD:

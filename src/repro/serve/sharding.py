@@ -93,6 +93,10 @@ class ServePlan:
         if SSD in mixers:
             nh = (cfg.ssm_expand * cfg.d_model) // cfg.ssm_head_dim
             checks.append(("ssm_heads", nh))
+            # the gate norm is taken per group: whole groups on a shard,
+            # or one group over all of them (one psum)
+            if cfg.ssm_ngroups > 1:
+                checks.append(("ssm_ngroups", cfg.ssm_ngroups))
         if RGLRU in mixers:
             checks.append(("lru_width", cfg.lru_width))
         bad = [f"{name}={n}" for name, n in checks if n % self.tp]

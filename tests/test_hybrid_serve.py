@@ -1,5 +1,6 @@
 """Universal paged-state subsystem: SSM (mamba2), RG-LRU + sliding-window
-(recurrentgemma) stacks served through the fused decode stack must be
+(recurrentgemma) and Mamba-2 + attention + MLP-only (nemotron-h) stacks
+served through the fused decode stack must be
 token-for-token identical to the eager dense-cache reference — plain and
 speculative k=4, single-device and 2x2 mesh — while recurrent layers hold
 O(1) device state (verify cost independent of position), ring layers
@@ -10,14 +11,28 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import smoke_config
+from repro.configs import get_config, smoke_config
+from repro.configs.nemotron_h_47b import kinds
 from repro.serve.engine import Request, ServeEngine, ServeSession
 from repro.serve.kvcache import PagedKVPool
 from repro.serve.paged_decode import (PagedKVState, build_fused_step,
                                       extract_prefill_pages)
 from repro.serve.paged_state import StateLayout, supports_paged_layout
 
-HYBRIDS = ("mamba2-780m", "recurrentgemma-2b")
+HYBRIDS = ("mamba2-780m", "recurrentgemma-2b", "nemotron-h-47b")
+
+
+def _smoke(arch):
+    """The arch at test size; nemotron-h as M-M*- with 2 SSD groups of 2
+    heads each (the grouped gate norm) and state 16."""
+    if arch != "nemotron-h-47b":
+        return smoke_config(arch)
+    return get_config(arch, num_layers=5, pattern=kinds("M-M*-"),
+                      d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
+                      d_ff=128, vocab_size=256, ssm_state=16,
+                      ssm_head_dim=32, ssm_ngroups=2, ssm_chunk=32,
+                      param_dtype="float32", compute_dtype="float32",
+                      remat="none")
 
 needs8 = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -27,7 +42,7 @@ needs8 = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def cfgs():
-    return {a: smoke_config(a) for a in HYBRIDS}
+    return {a: _smoke(a) for a in HYBRIDS}
 
 
 @pytest.fixture(scope="module")
@@ -378,3 +393,132 @@ def test_hybrid_traffic_mix(cfgs, params, arch):
     assert r["n_done"] + r["n_cancelled"] + r["n_rejected"] \
         + r.get("n_errors", 0) == r["n_trace"]
     assert r["cancelled_pages_freed"]
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H: stateless layers, the one-pass prompt chunk, fresh rows
+# ---------------------------------------------------------------------------
+def test_nemotron_layout(cfgs):
+    lay = StateLayout(cfgs["nemotron-h-47b"], 4)
+    assert lay.roles == ["rec", "none", "rec", "kv", "none"]
+    assert (lay.n_kv, lay.n_ssd, lay.n_rg) == (1, 2, 0)
+    assert supports_paged_layout(cfgs["nemotron-h-47b"])
+
+
+def test_grouped_norm_with_one_group_is_the_norm():
+    from repro.models.layers import rms_norm
+    from repro.models.ssm import group_rms_norm
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 96))
+    scale = jax.random.normal(jax.random.PRNGKey(1), (96,))
+    np.testing.assert_array_equal(np.asarray(group_rms_norm(x, scale, 1)),
+                                  np.asarray(rms_norm(x, scale)))
+    # each group is normed on its own
+    two = np.asarray(group_rms_norm(x, jnp.zeros(96), 2))
+    for half in (two[..., :48], two[..., 48:]):
+        np.testing.assert_allclose((half ** 2).mean(-1), 1.0, rtol=1e-4)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "nemotron-h-47b"])
+def test_wide_step_state_matches_token_steps(cfgs, params, arch):
+    """In one 16-wide step, rows carrying 1, 5 and 16 real tokens end with
+    the SSD state and conv taps that feeding those tokens one step at a
+    time gives (the wide step reads each state once: the chunk form)."""
+    cfg = cfgs[arch]
+    eng = ServeEngine(cfg, params=params[arch],
+                      kv_pool=PagedKVPool(page_tokens=16))
+    layout = StateLayout(cfg, 16)
+    counts = [1, 5, 16]
+    rng = np.random.default_rng(3)
+    toks = rng.integers(0, cfg.vocab_size, (3, 16)).astype(np.int32)
+    key = jax.random.PRNGKey(0)
+
+    def state():
+        return PagedKVState(PagedKVPool(page_tokens=16), 64, cfg.num_layers,
+                            cfg.num_kv_heads, cfg.head_dim, mode="fused",
+                            batch_hint=3, tail_slots=2, layout=layout)
+
+    wide = state()
+    step = build_fused_step(eng.model, wide.slots, k=16, layout=layout,
+                            drafts=False)
+    wide.run_spec(step, eng.params, toks, [0, 1, 2], np.zeros(3, np.int32),
+                  key, keep_fixed=np.asarray(counts, np.int32),
+                  keep_cap=np.zeros(3, np.int32))
+    wide.end_step([0, 1, 2], counts)
+    narrow = state()
+    step1 = build_fused_step(eng.model, narrow.slots, layout=layout)
+    for j in range(16):
+        seqs = [s if j < n else -1 for s, n in zip(range(3), counts)]
+        narrow.run_fused(step1, eng.params, toks[:, j], seqs,
+                         np.full(3, j, np.int32), key)
+    for seq in range(3):
+        got = wide._rec.read_slot(wide._rec_slot[seq])
+        want = narrow._rec.read_slot(narrow._rec_slot[seq])
+        for name in ("ssd_state", "ssd_conv"):
+            np.testing.assert_allclose(got[name], want[name], rtol=1e-5,
+                                       atol=1e-5, err_msg=(seq, name))
+    for st in (wide, narrow):
+        for seq in range(3):
+            st.free_seq(seq)
+
+
+@pytest.mark.parametrize("arch", HYBRIDS)
+def test_admission_uploads_no_state(cfgs, params, arch):
+    """A row's recurrent state starts from zero on the device: serving a
+    batch adds nothing to the store's host->device writes, and the
+    serve.rec_store spans count each admitted row fresh once."""
+    from repro.serve import tracing
+    cfg = cfgs[arch]
+    eng = _fused(cfg, params[arch])
+    sess = ServeSession(eng, capacity=64, max_active=2)
+    t0 = __import__("time").perf_counter()
+    reqs = _reqs(cfg, n=3)
+    for r in reqs:
+        sess.submit(r)
+    while not sess.done:
+        sess.step()
+    assert sess.state._rec.writes == 0
+    fresh = sum(s.counts.get("fresh", 0)
+                for s in tracing.spans(t0, name="serve.rec_store"))
+    assert fresh == len(reqs)
+    sess.close()
+
+
+def test_rec_store_is_sized_once(cfgs):
+    from repro.serve.paged_state import RecurrentStore
+    store = RecurrentStore(StateLayout(cfgs["nemotron-h-47b"], 4),
+                           batch_hint=3)
+    assert store.slots == 4                 # 3 rows + the trash slot
+    for _ in range(3):
+        store.alloc()
+    with pytest.raises(RuntimeError, match="never grows"):
+        store.alloc()
+
+
+def test_store_rows_skip_the_trash_slot():
+    """The per-row loop over a store reads zeros for a fresh row, writes
+    every live row's block in place, and moves nothing for a dead row."""
+    from repro.serve.paged_state import TRASH_SLOT, StoreRows
+    store = jnp.arange(2 * 4 * 3, dtype=jnp.float32).reshape(2, 4, 3)
+    rows = StoreRows(1, jnp.asarray([TRASH_SLOT, 2, 3]),
+                     jnp.asarray([0, 0, 1]))
+    outs, new = rows.map(store,
+                         lambda blk, inp: (blk.sum() + inp, blk + 1.0),
+                         jnp.asarray([10.0, 20.0, 30.0]))
+    want = np.asarray(store).copy()
+    want[1, 2] += 1.0
+    want[1, 3] = 1.0                       # fresh: zeros, then advanced
+    np.testing.assert_array_equal(np.asarray(new), want)
+    np.testing.assert_array_equal(np.asarray(outs),
+                                  [0.0, store[1, 2].sum() + 20.0, 30.0])
+
+
+def test_speculation_refused_when_checkpoints_do_not_fit(cfgs, params,
+                                                         monkeypatch):
+    from repro.serve import engine as engine_mod
+    cfg = cfgs["nemotron-h-47b"]
+    eng = _fused(cfg, params["nemotron-h-47b"], speculate=4)
+    monkeypatch.setattr(engine_mod, "_free_device_bytes", lambda: 1 << 16)
+    with pytest.raises(ValueError, match="bytes of recurrent-state"):
+        ServeSession(eng, capacity=64, max_active=4)
+    monkeypatch.setattr(engine_mod, "_free_device_bytes", lambda: 1 << 40)
+    ServeSession(eng, capacity=64, max_active=4).close()

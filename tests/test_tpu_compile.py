@@ -131,3 +131,51 @@ def test_fused_step_compiles_at_16_layers(one_chip, monkeypatch, k):
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("k", [1, 16])
+def test_nemotron_stage_step_fits_one_chip(one_chip, monkeypatch, k):
+    """The fused step of the 11-layer Nemotron-H-47B stage (published
+    layers 40-50, 16384 vocabulary rows) at 32 rows compiles for one chip
+    and fits it: plain decode, and the 16-wide prompt-chunk step, whose
+    SSD layers read and write each row's state once (k stacked states of
+    84.5 MB a row would not fit beside the 2.8 GB store)."""
+    from repro.models import Model
+    from repro.serve.paged_decode import build_fused_step
+    from repro.serve.paged_state import StateLayout, rec_array_names
+    from repro.models.ssm import ssm_dims
+
+    monkeypatch.setattr(api, "default_interpret", lambda: False)
+    cfg = get_config("nemotron-h-47b", num_layers=11, first_layer=40,
+                     vocab_size=16384)
+    model = Model(cfg)
+    lay = StateLayout(cfg, PAGE_TOKENS)
+    rows, slots, pool_slots = 32, 104, 4096     # capacity 1536 tokens
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          model.abstract_params())
+    pool = (lay.n_kv, pool_slots, PAGE_TOKENS, cfg.num_kv_heads,
+            cfg.head_dim)
+    _din, nh, conv_dim = ssm_dims(cfg)
+    store = {"ssd_state": sds((lay.n_ssd, rows + 1, nh, cfg.ssm_head_dim,
+                               cfg.ssm_state), "float32"),
+             "ssd_conv": sds((lay.n_ssd, rows + 1, cfg.ssm_conv_width - 1,
+                              conv_dim), "bfloat16")}
+    arrays = (sds(pool, "float32"), sds(pool, "float32"),
+              sds(pool, "int8"), sds(pool, "int8"),
+              sds(pool[:-1], "float32"), sds(pool[:-1], "float32")) \
+        + tuple(store[n] for n in rec_array_names(lay))
+    control = sds((rows, lay.cols(slots, k).width), "int32")
+    key = sds((2,), "uint32")
+    step = build_fused_step(model, slots, k=k, layout=lay, drafts=False)
+    inputs = (params, arrays, sds((rows,), "int32"), control, key) \
+        if k == 1 else (params, arrays, control, key)
+    compiled = step.lower(*inputs).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, \
+        (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
