@@ -1,12 +1,13 @@
 """KernelSpec for paged decode attention (serving hot path).
 
-The tune space is (pages_per_block, head_block): more pages / kv heads per
-grid step cut dispatch overhead at the price of VMEM for the fetched page
-blocks — the same window-vs-resource trade NERO searches (thesis §3.3.1),
-here on the serving side. ``example_inputs`` builds a mixed-tier pool
-(odd page ids are "slow": int8 + per-row scale, zeros in the float pool)
-so every consumer — conformance tests, precision sweeps, bench_nero —
-exercises the dequant-on-load path by default.
+The tune space is (pages_per_block, head_block): more pages per loop trip
+and more kv heads per grid step cut fixed costs at the price of VMEM for
+the double-buffered page blocks — the same window-vs-resource trade NERO
+searches (thesis §3.3.1), here on the serving side. ``example_inputs``
+builds a mixed-tier pool (odd page ids are "slow": int8 + per-row scale,
+zeros in the float pool) so every consumer — conformance tests,
+precision sweeps, bench_nero — exercises the dequant-on-load path by
+default.
 """
 from __future__ import annotations
 
@@ -38,35 +39,48 @@ def head_block_ok(hkv: int, hb: int) -> bool:
     return hkv % hb == 0 and (hb == hkv or hb % LANE == 0)
 
 
+# fixed costs of the kernel's page loop, fitted to its times on a TPU v5e
+# at the served shapes: issuing and waiting for one page's six copies, and
+# one trip of the loop (dequantizing, scoring and the online-softmax update
+# of a block)
+PAGE_COPY_S = 0.15e-6
+BLOCK_S = 1.0e-6
+
+
 def paged_cost(grid_shape, tile: dict, dtype_bytes: int) -> tuple | None:
-    """tile = {"pages_per_block": ppb, "head_block": hb}; None for a tile
-    that does not divide the grid or that the TPU refuses
-    (`head_block_ok`). Decode is
-    traffic-bound: the whole paged KV streams once per kv head (fast float
-    + int8 + scale are all fetched; tier saving shows up as the int8 pool
-    being the only populated one for slow pages), while q/out are k
-    token(s). Larger blocks amortize the per-step dispatch latency against
-    VMEM for the fetched pages. The k query rows (speculative verify) ride
-    along the folded head axis: q/out traffic, flops and the q/out/softmax
-    VMEM scale by k while the dominant KV stream does not — the cost-model
-    face of "more compute per byte moved"."""
+    """tile = {"pages_per_block": ppb, "head_block": hb}; None for a head
+    block the TPU refuses (`head_block_ok`). A grid step (one row, one
+    head block) streams the row's live pages, at most the table's
+    ``slots``, in blocks of ppb: the model charges the bytes of a full
+    table (float + int8 pages and each page's scale row, read whatever
+    tier holds the page) at HBM bandwidth, or its MXU FLOPs, plus a fixed
+    cost per grid step, per page copied and per block. A block scores all
+    its (token, head) key rows against all the head block's query rows,
+    hb x the useful FLOPs, and its last, partial block is computed in
+    full. VMEM holds two buffers of ppb pages of all six arrays, the
+    block's dequantized K and V and its scores, and q/out. The k query
+    rows (speculative verify, prompt chunks) ride along the folded head
+    axis: q/out traffic, FLOPs and the q/out/softmax VMEM scale by k while
+    the dominant KV stream does not — the cost-model face of "more
+    compute per byte moved"."""
     b, pages, t, slots, hq, hkv, d, k = grid_shape
-    ppb, hb = tile["pages_per_block"], tile["head_block"]
-    if slots % ppb or not head_block_ok(hkv, hb):
+    ppb, hb = min(tile["pages_per_block"], slots), tile["head_block"]
+    if not head_block_ok(hkv, hb):
         return None
-    g = hq // hkv
-    # bytes of one (page, head-block) row set: float pool + int8 + scale
-    row = t * hb * (d * (dtype_bytes + 1) + dtype_bytes)
-    # q + out blocks, k + v page blocks (double buffered), fp32 (m, l, acc)
-    vmem = (2 * hb * k * g * d * dtype_bytes + 2 * 2 * ppb * row
-            + hb * k * g * (d + 2) * 4)
-    traffic = (2 * b * k * hq * d * dtype_bytes             # q + out
-               + 2 * b * hkv * slots * (row // hb))         # k + v pages
-    flops = 4 * b * k * hq * slots * t * d
-    steps = b * (hkv // hb) * (slots // ppb)
+    steps = b * (hkv // hb)
+    blocks = -(-slots // ppb)
+    rows = ppb * t * hb                             # key rows of a block
+    qr = hb * k * (hq // hkv)                       # query rows
+    w = -(-(t * hkv) // LANE) * LANE                # a page's scale row
+    page = t * hb * d * (dtype_bytes + 1) + w * 4   # one array pair + scales
+    vmem = (2 * 2 * ppb * page + 2 * rows * d * 4 + 2 * qr * rows * 4
+            + 2 * 2 * qr * d * dtype_bytes + qr * (d + 2) * 4)
+    traffic = 2 * b * k * hq * d * dtype_bytes + 2 * steps * slots * page
+    flops = 4 * steps * blocks * qr * rows * d
     align = 1.0 if d % LANE == 0 else 1.0 + (LANE - d % LANE) / LANE
     time = max(traffic * align / HBM_BW, flops / PEAK_FLOPS) \
-        + steps * GRID_STEP_OVERHEAD_S
+        + steps * (GRID_STEP_OVERHEAD_S + slots * PAGE_COPY_S
+                   + blocks * BLOCK_S)
     return vmem, time
 
 

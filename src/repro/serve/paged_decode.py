@@ -56,6 +56,7 @@ import numpy as np
 from repro.configs.base import (ATTN, LOCAL_ATTN, MIXER_NONE, MLP_DENSE,
                                 MLP_MOE, MLP_NONE, RGLRU, SSD)
 from repro.kernels import api
+from repro.kernels.paged_attention.paged_attention import live_pages
 from repro.models.attention import decode_qkv
 from repro.models.layers import lm_head_apply, rms_norm
 from repro.models.transformer import mlp_tail
@@ -397,7 +398,11 @@ class PagedKVState:
         rewrites), and copy each row's cached page-table row into the
         layer-uniform control block the fused step consumes. The span
         ``serve.begin_step`` counts the live ``rows`` and those
-        ``rebuilt`` from the pool this step (admission, swap-in). A stack
+        ``rebuilt`` from the pool this step (admission, swap-in), the
+        pages one attention layer's kernel call copies (``streamed``,
+        `live_pages` summed over the batch rows, dead rows included; 0
+        for a stack with no KV pages) and the batch's table slots
+        (``table``: rows x slots). A stack
         with recurrent layers gives each row its state slot inside the
         child span ``serve.rec_store``, whose count ``fresh`` is the rows
         whose slot was allocated this step: the step reads zeros for them
@@ -544,7 +549,12 @@ class PagedKVState:
                 control[i, c_row] = tail
                 control[i, c_pos] = positions[i]
                 control[i, c_len] = n * t + tail + 1
-            sp.set(rows=len(live), rebuilt=rebuilt)
+            # what one attention layer's kernel call copies (a stack with
+            # no KV pages calls none), against the batch's table slots
+            streamed = live_pages(control[:, c_len], k, t, s).sum() \
+                if self.num_layers else 0
+            sp.set(rows=len(live), rebuilt=rebuilt, streamed=int(streamed),
+                   table=b * s)
             self._step = {"seq_ids": list(seq_ids), "control": control,
                           "table": None, "lengths": None}
         self.gather_s += sp.elapsed

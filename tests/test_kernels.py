@@ -108,3 +108,96 @@ def test_flash_attention_grads_through_registry_dispatch():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+def _nan_past_live_pages(inputs, k):
+    """`example_inputs` made ragged: row 0 is dead (length 1 on the trash
+    slot), the other rows take their given lengths; every table entry past
+    a row's live pages names a slot of NaN, and every position no query
+    row sees, in the trash slot and in each row's last live page, is NaN
+    too. Returns (NaN pools, the same pools with zeros there)."""
+    from repro.kernels.paged_attention.paged_attention import live_pages
+    t = inputs["k_pages"].shape[1]
+    table, lengths = inputs["page_table"].copy(), inputs["lengths"].copy()
+    nan_slot = min(set(range(inputs["k_pages"].shape[0])) - set(table.flat))
+    table[0], lengths[0] = table[0, 0], 1          # row 0's page: the trash
+    unseen = np.zeros(inputs["k_pages"].shape[:2], bool)     # (slot, pos)
+    for r, (row, n) in enumerate(zip(table, lengths)):
+        live = int(live_pages(n, k, t, table.shape[1]))
+        row[live:] = nan_slot
+        for j, slot in enumerate(row[:live]):
+            pos = j * t + np.arange(t)
+            unseen[slot] |= pos >= n + k - 1
+    unseen[nan_slot] = True
+    inputs = {**inputs, "page_table": table, "lengths": lengths}
+    clean, poisoned = dict(inputs), dict(inputs)
+    for name in ("k_pages", "v_pages", "k_scale", "v_scale"):
+        a = inputs[name]
+        mask = unseen.reshape(unseen.shape + (1,) * (a.ndim - 2))
+        clean[name] = np.where(mask, 0.0, a).astype(a.dtype)
+        poisoned[name] = np.where(mask, np.nan, a).astype(a.dtype)
+    return poisoned, clean
+
+
+@pytest.mark.parametrize("k,ppb,slots,stacked", [
+    (1, 1, 7, False), (1, 2, 7, False), (1, 4, 7, True),
+    (4, 2, 7, False), (4, 4, 6, True), (16, 1, 7, False),
+    (16, 2, 7, True), (16, 4, 6, False)])
+def test_paged_attention_reads_only_live_pages(k, ppb, slots, stacked,
+                                              monkeypatch):
+    """Only each row's live pages are copied (six copies a page, counted
+    as they start), and what lies past them never reaches the output:
+    NaN in every table entry past a row's live pages and in every
+    position no query row sees leaves the output finite and equal to the
+    reference on clean pools. Lengths of 1, ppb x t and ppb x t + 1, a
+    dead row on its trash page, k query rows whose later rows cross a
+    page block's edge, mixed-tier pools, tables whose width is not a
+    multiple of ppb."""
+    from repro.kernels.paged_attention import paged_attention as kernel
+    started = []
+
+    class Counted:
+        def __init__(self, copy):
+            self.copy = copy
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    make = kernel.pltpu.make_async_copy
+    monkeypatch.setattr(kernel.pltpu, "make_async_copy",
+                        lambda *a, **kw: Counted(make(*a, **kw)))
+    spec = registry.get("paged_attention")
+    t = 16
+    shape = {"b": 6, "pages": 6 * slots + 2, "page_tokens": t,
+             "slots": slots, "hq": 4, "hkv": 2, "d": 32, "k": k}
+    inputs = spec.example_inputs(shape=shape, seed=k + ppb)
+    edge = ppb * t
+    want_len = [1, 1, edge, edge + 1, edge - k // 2, slots * t - (k - 1)]
+    inputs["lengths"] = np.minimum(want_len, slots * t - (k - 1)) \
+        .astype(np.int32)
+    poisoned, clean = _nan_past_live_pages(inputs, k)
+    names = spec.arg_names
+
+    def args(pools, layered):
+        out = [jnp.asarray(pools[n]) for n in names]
+        if layered:     # layer 1 of two: layer 0 all NaN
+            out = [a if i in (0, 7, 8) else
+                   jnp.stack([jnp.full_like(a, jnp.nan) if
+                              jnp.issubdtype(a.dtype, jnp.floating)
+                              else a, a])
+                   for i, a in enumerate(out)] + [jnp.int32(1)]
+        return out
+
+    want = api.run(spec.name, *args(clean, False), backend="ref")
+    got = kernel.paged_attention_pallas(*args(poisoned, stacked),
+                                        pages_per_block=ppb, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    assert len(started) == 6 * kernel.live_pages(
+        poisoned["lengths"], k, t, slots).sum()
+    tol = spec.tol["float32"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)

@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.sibyl.traces import DecodeTraceRecorder
+from repro.kernels.paged_attention.paged_attention import live_pages
 from repro.serve import tracing
 from repro.serve.engine import Request, ServeEngine, ServeSession
 from repro.serve.kvcache import PagedKVPool
@@ -39,6 +40,14 @@ def ring_params(ring_cfg):
     return ServeEngine(ring_cfg).params
 
 
+def len_column(state, k):
+    """The control block's KV-length column."""
+    s = state.slots
+    if state.layout is not None:
+        return state.layout.cols(s, k).len
+    return s + 3 if k == 1 else s + 4
+
+
 def reference_control(state, control, seq_ids, k):
     """``control`` with its page-derived columns rebuilt from the pool
     and the mirror: each live row's page groups zipped from the pool's
@@ -47,11 +56,8 @@ def reference_control(state, control, seq_ids, k):
     ref = control.copy()
     pool, dev, t, s = state.pool, state._device, state.pool.page_tokens, \
         state.slots
-    if state.layout is not None:
-        cc = state.layout.cols(s, k)
-        c_tail, c_len = cc.tail, cc.len
-    else:
-        c_tail, c_len = s, (s + 3 if k == 1 else s + 4)
+    c_tail = s if state.layout is None else state.layout.cols(s, k).tail
+    c_len = len_column(state, k)
     b = len(seq_ids)
     shards = dev.shards if dev is not None else 1
     ref[:, :s] = 0
@@ -111,18 +117,24 @@ def check_every_step(state):
     """Wrap ``state.begin_step``: each control block it returns must equal
     `reference_control`, the mirror must hold what the pool holds
     (`check_mirror`), and the state's own invariant check must pass.
-    Returns the list the step's ``(rows, rebuilt)`` counts land in."""
+    Its ``streamed`` count must be the kernel's `live_pages` over the
+    reference's lengths, and ``table`` rows x slots. Returns the list the
+    step's ``(rows, rebuilt)`` counts land in."""
     counts = []
     begin = state.begin_step
 
     def checked(seq_ids, positions, k=1, **kw):
         control = begin(seq_ids, positions, k=k, **kw)
-        np.testing.assert_array_equal(
-            control, reference_control(state, control, seq_ids, k))
+        ref = reference_control(state, control, seq_ids, k)
+        np.testing.assert_array_equal(control, ref)
         check_mirror(state, seq_ids)
         state.check_invariants()
         c = tracing.spans(name="serve.begin_step")[-1].counts
         counts.append((c["rows"], c["rebuilt"]))
+        s, t = state.slots, state.pool.page_tokens
+        lengths = ref[:, len_column(state, k)]
+        assert c["streamed"] == live_pages(lengths, k, t, s).sum()
+        assert c["table"] == len(seq_ids) * s
         return control
 
     state.begin_step = checked
@@ -255,6 +267,26 @@ def test_control_block_matches_the_pool(case, cfg, params, ring_cfg,
     rows = sum(r for r, _ in counts)
     rebuilt = sum(b for _, b in counts)
     assert rows > 0 and 0 < rebuilt < rows     # reused far more than built
+
+
+def test_streamed_pages_on_a_batch_with_dead_rows(cfg, params):
+    """Two live rows of four: `check_every_step` holds ``streamed`` to the
+    kernel's `live_pages` over the reference lengths at every step; each
+    dead row adds the one trash page the kernel copies for it, and the
+    batch copies far fewer pages than its table has slots."""
+    eng = ServeEngine(cfg, params=params, kv_pool=PagedKVPool(page_tokens=T))
+    ses = ServeSession(eng, capacity=64, max_active=4, chunked_prefill=False)
+    check_every_step(ses.state)
+    ses.submit(Request(_prompt(cfg, 9, 30), 6))
+    ses.submit(Request(_prompt(cfg, 21, 31), 9))
+    for _ in range(3):
+        ses.step()
+        c = tracing.spans(name="serve.begin_step")[-1].counts
+        rows = c["table"] // ses.state.slots
+        assert c["rows"] == 2 and rows == 4
+        # two dead rows' trash pages, and 3 + 6 pages of 4 tokens at least
+        assert 2 + 9 <= c["streamed"] < c["table"]
+    _drain(ses)
 
 
 def test_steady_decode_rebuilds_and_syncs_nothing(cfg, params):

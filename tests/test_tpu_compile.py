@@ -18,7 +18,9 @@ from repro.configs import get_config
 from repro.core.autotune import autotune_kernel
 from repro.kernels import api, registry
 
-BATCH, PAGE_TOKENS, CAPACITY_SLOTS = 4, 16, 256
+# the starcoder2 chat cell as served: 32 rows, a 104-slot page table, a
+# pool of 4096 slots (capacity 1536 tokens)
+BATCH, PAGE_TOKENS, SLOTS, CAPACITY_SLOTS = 32, 16, 104, 4096
 
 
 @pytest.fixture(scope="module")
@@ -47,13 +49,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _served_args(one_chip, k, q_dtype, pool_dtype):
-    """Argument shapes of the fused step's kernel call for starcoder2-7b
-    cut to 16 layers: layer-stacked pools, a 544-token page table."""
-    cfg = get_config("starcoder2-7b", num_layers=16)
-    slots = 40
+def _served_args(one_chip, k, q_dtype, pool_dtype, model="starcoder2"):
+    """Argument shapes of the fused step's kernel call: starcoder2-7b cut
+    to 16 layers (36 q / 4 kv heads), or the Nemotron-H stage's one
+    attention layer (64 q / 8 kv heads); layer-stacked pools."""
+    if model == "starcoder2":
+        cfg = get_config("starcoder2-7b", num_layers=16)
+        layers = cfg.num_layers
+    else:
+        cfg = get_config("nemotron-h-47b", num_layers=11, first_layer=40,
+                         vocab_size=16384)
+        layers = 1
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    pool = (cfg.num_layers, CAPACITY_SLOTS, PAGE_TOKENS, hkv, d)
+    pool = (layers, CAPACITY_SLOTS, PAGE_TOKENS, hkv, d)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
@@ -63,7 +71,7 @@ def _served_args(one_chip, k, q_dtype, pool_dtype):
     return (sds(q, q_dtype), sds(pool, pool_dtype), sds(pool, pool_dtype),
             sds(pool, "int8"), sds(pool, "int8"),
             sds(pool[:-1], pool_dtype), sds(pool[:-1], pool_dtype),
-            sds((BATCH, slots), "int32"), sds((BATCH,), "int32"),
+            sds((BATCH, SLOTS), "int32"), sds((BATCH,), "int32"),
             sds((), "int32"))
 
 
@@ -76,7 +84,7 @@ def _compile(args, tile):
 @pytest.mark.parametrize("q_dtype,pool_dtype", [
     ("bfloat16", "bfloat16"), ("float32", "float32"),
     ("bfloat16", "float32")])   # the last is what the fused step serves
-@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("k", [1, 4, 16])
 def test_paged_attention_auto_tile_compiles(one_chip, k, q_dtype,
                                             pool_dtype):
     args = _served_args(one_chip, k, q_dtype, pool_dtype)
@@ -85,18 +93,30 @@ def test_paged_attention_auto_tile_compiles(one_chip, k, q_dtype,
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("k", [1, 4])
-def test_every_tune_space_tile_compiles(one_chip, k):
-    """Every tile the autotuner can pick at the served shapes (those the
-    cost model does not reject) is one the TPU accepts."""
+def _every_tile_compiles(one_chip, k, model):
     spec = registry.get("paged_attention")
-    args = _served_args(one_chip, k, "bfloat16", "float32")
+    args = _served_args(one_chip, k, "bfloat16", "float32", model)
     grid = spec.grid_of(*args)
     tiles = [c.params for c in
              autotune_kernel(spec, grid, dtype="bfloat16")["candidates"]]
     assert tiles
     for tile in tiles:
         _compile(args, tile)
+
+
+@pytest.mark.parametrize("k", [1, 4, 16])
+def test_every_tune_space_tile_compiles(one_chip, k):
+    """Every tile the autotuner can pick at the served shapes (those the
+    cost model does not reject) is one the TPU accepts: bf16 q, float32
+    and int8 pools of starcoder2's 16 layers."""
+    _every_tile_compiles(one_chip, k, "starcoder2")
+
+
+@pytest.mark.parametrize("k", [1, 16])
+def test_every_tile_compiles_for_the_nemotron_layer(one_chip, k):
+    """The same for the Nemotron-H stage's attention layer: 64 q / 8 kv
+    heads, one layer's pool."""
+    _every_tile_compiles(one_chip, k, "nemotron")
 
 
 @pytest.mark.parametrize("k", [1, 4])
@@ -113,7 +133,6 @@ def test_fused_step_compiles_at_16_layers(one_chip, monkeypatch, k):
     cfg = get_config("starcoder2-7b", num_layers=16)
     model = Model(cfg)
     lay = StateLayout(cfg, PAGE_TOKENS)
-    slots = 40
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
@@ -122,9 +141,9 @@ def test_fused_step_compiles_at_16_layers(one_chip, monkeypatch, k):
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
                           model.abstract_params())
     args = _served_args(one_chip, 1, "bfloat16", "float32")[1:7]
-    control = sds((BATCH, lay.cols(slots, k).width), "int32")
+    control = sds((BATCH, lay.cols(SLOTS, k).width), "int32")
     key = sds((2,), "uint32")
-    step = build_fused_step(model, slots, k=k, layout=lay)
+    step = build_fused_step(model, SLOTS, k=k, layout=lay)
     inputs = (params, args, sds((BATCH,), "int32"), control, key) \
         if k == 1 else (params, args, control, key)
     compiled = step.lower(*inputs).compile()
@@ -150,7 +169,7 @@ def test_nemotron_stage_step_fits_one_chip(one_chip, monkeypatch, k):
                      vocab_size=16384)
     model = Model(cfg)
     lay = StateLayout(cfg, PAGE_TOKENS)
-    rows, slots, pool_slots = 32, 104, 4096     # capacity 1536 tokens
+    rows, slots, pool_slots = BATCH, SLOTS, CAPACITY_SLOTS
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
