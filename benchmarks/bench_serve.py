@@ -1,18 +1,17 @@
 """Serve-layer benchmarks: per-token decode latency + host-sync counts
-across the three decode modes, steady-state gather bookkeeping, and
+across the two decode modes, steady-state host bookkeeping, and
 continuous-batching throughput.
 
 The headline suite decodes the same batch through ``fused`` (one jitted
-device-resident graph per token), ``eager`` (per-layer reference: ~2 host
-crossings per layer per token) and ``numpy`` (host pool restack per layer
-per token), reporting per-token latency and the explicit host<->device
-transfer count per token (`PagedKVState.transfer_counts`). The acceptance
-bar is fused beating eager on per-token latency with a depth-independent
-transfer count (~2/token). Note interpret-mode Pallas on CPU charges
-every kernel for total operand size, which *understates* the fused path's
-advantage: on real hardware the numpy baseline additionally pays a
-host->device copy of the whole pool every layer every step, and eager
-pays per-layer dispatch + round-trip latency the fused graph never sees."""
+device-resident graph per token) and ``eager`` (per-layer reference: ~2
+host crossings per layer per token), reporting per-token latency and the
+explicit host<->device transfer count per token
+(`PagedKVState.transfer_counts`). The acceptance bar is fused beating
+eager on per-token latency with a depth-independent transfer count
+(~2/token). Note interpret-mode Pallas on CPU charges every kernel for
+total operand size, which *understates* the fused path's advantage: on
+real hardware eager pays per-layer dispatch + round-trip latency the
+fused graph never sees."""
 from __future__ import annotations
 
 import time
@@ -49,7 +48,7 @@ def run():
                  f"_devices={meta['devices']}"
                  f"_mesh={meta['mesh'] or 'host'}"))
     step_us = {}
-    for mode in ("numpy", "eager", "fused"):
+    for mode in ("eager", "fused"):
         pool = PagedKVPool(page_tokens=PAGE_TOKENS)
         eng = ServeEngine(cfg, params=params, kv_pool=pool, decode_mode=mode,
                           seed=SEED, mesh=mesh if mode == "fused" else None)
@@ -69,49 +68,38 @@ def run():
                      f"h2d={h2d}_d2h={d2h}_steps={steps}"))
     rows.append((f"serve.decode_step.b{batch}.eager_over_fused", 0.0,
                  f"{step_us['eager'] / step_us['fused']:.2f}x"))
-    rows.append((f"serve.decode_step.b{batch}.numpy_over_fused", 0.0,
-                 f"{step_us['numpy'] / step_us['fused']:.2f}x"))
 
-    # isolated steady-state per-step HOST work (no kernel, no model):
-    # numpy restacks the whole pool per step (O(pages)); the fused path's
-    # host side is pure bookkeeping — touch + page-table/control build +
-    # tail counters (O(batch)); its row scatter happens inside the jitted
-    # step graph and is charged to the decode_step rows above
+    # isolated steady-state per-step HOST work (no kernel, no model): the
+    # fused path's host side is pure bookkeeping — touch + page-table/
+    # control build + tail counters (O(batch)); its row scatter happens
+    # inside the jitted step graph and is charged to the decode_step rows
+    # above
     from repro.serve.paged_decode import PagedKVState
     t, hkv, hd, b, npages = PAGE_TOKENS, 4, 16, 4, 256
-    gather_us = {}
-    for mode in ("numpy", "fused"):
-        pool = PagedKVPool(page_tokens=t)
-        state = PagedKVState(pool, capacity=(npages // b + 16) * t,
-                             num_layers=1, hkv=hkv, hd=hd, mode=mode)
-        rng = np.random.default_rng(0)
-        for seq in range(b):
-            kv = rng.standard_normal((npages // b * t, hkv, hd)) \
-                .astype(np.float32)
-            state.write_prefill(0, seq, kv, kv.copy())
-        kr = rng.standard_normal((b, hkv, hd)).astype(np.float32)
-        seqs = list(range(b))
-        pos = np.zeros(b, np.int32)
+    pool = PagedKVPool(page_tokens=t)
+    state = PagedKVState(pool, capacity=(npages // b + 16) * t,
+                         num_layers=1, hkv=hkv, hd=hd)
+    rng = np.random.default_rng(0)
+    for seq in range(b):
+        kv = rng.standard_normal((npages // b * t, hkv, hd)) \
+            .astype(np.float32)
+        state.write_prefill(0, seq, kv, kv.copy())
+    seqs = list(range(b))
+    pos = np.zeros(b, np.int32)
 
-        def step():
-            state.begin_step(seqs, pos)
-            if mode == "numpy":
-                state.append_step_rows(0, kr, kr)
-                state.gather(0, seqs)          # the per-step restack cost
-            state.end_step(seqs)
+    def step():
+        state.begin_step(seqs, pos)
+        state.end_step(seqs)
 
-        for _ in range(t + 2):                 # warm all shapes/slots
-            step()
-        n = 50
-        t0 = time.perf_counter()
-        for _ in range(n):
-            step()
-        gather_us[mode] = us_per(time.perf_counter() - t0, n)
-        label = "numpy_gather" if mode == "numpy" else "fused_bookkeeping"
-        rows.append((f"serve.gather_steady.{label}", gather_us[mode],
-                     f"pool={npages}pages_b={b}"))
-    rows.append(("serve.gather_steady.numpy_over_fused", 0.0,
-                 f"{gather_us['numpy'] / gather_us['fused']:.2f}x"))
+    for _ in range(t + 2):                     # warm all shapes/slots
+        step()
+    n = 50
+    t0 = time.perf_counter()
+    for _ in range(n):
+        step()
+    rows.append(("serve.gather_steady.fused_bookkeeping",
+                 us_per(time.perf_counter() - t0, n),
+                 f"pool={npages}pages_b={b}"))
 
     # continuous batching (fused): staggered per-request lengths, 2 rows
     pool = PagedKVPool(page_tokens=PAGE_TOKENS)

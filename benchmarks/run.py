@@ -7,8 +7,8 @@
   bench_leaper     Ch.6  Fig 6-4/T6.6   few-shot cross-platform transfer
   bench_sibyl      Ch.7  Figs 7-10..19  RL data placement vs baselines
   bench_roofline   —     §Dry-run/§Roofline cell table
-  bench_serve      —     serve layer: device vs numpy page gather,
-                         continuous-batching throughput
+  bench_serve      —     serve layer: fused vs eager decode, host
+                         bookkeeping, continuous-batching throughput
   bench_traffic    —     open-loop trace replay through the async front
                          end; persists BENCH_traffic.json trajectory
 

@@ -212,8 +212,7 @@ def fused_step_text(eng, capacity, max_active) -> str:
     control = state.begin_step([-1] * n_rows, np.zeros(n_rows, np.int32))
     step = eng._fused_step_fn(state.slots, True, 1.0, k=1)
     lowered = step.lower(eng.params, state.device_arrays,
-                         jnp.zeros(n_rows, jnp.int32), jnp.asarray(control),
-                         jax.random.PRNGKey(0))
+                         jnp.asarray(control), jax.random.PRNGKey(0))
     sess.close()
     return lowered.compile().as_text()
 

@@ -66,10 +66,9 @@ def main():
                     help="Sibyl DQN tier placement (reward: gather latency"
                          " + slow-hit penalty)")
     ap.add_argument("--decode-mode", default="fused",
-                    choices=("fused", "eager", "numpy"),
+                    choices=("fused", "eager"),
                     help="fused = one jitted device-resident step per token"
-                         " (default); eager = per-layer reference path;"
-                         " numpy = host-gather fallback")
+                         " (default); eager = per-layer reference path")
     ap.add_argument("--speculate", type=int, default=0, metavar="K",
                     help="speculative decode: verify K-token runs per "
                          "fused step (requires --paged/--continuous and "

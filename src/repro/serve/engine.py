@@ -12,12 +12,11 @@
   the pool tracks the live working set. Greedy tokens are identical to
   running each request alone through the static-batch paged path.
 
-Paged decode runs in one of three modes (``decode_mode``): ``fused``
-(default) executes the whole per-token step as a single jitted,
-device-resident graph — two host/device crossings per token, independent
-of depth; ``eager`` is the per-layer reference path the fused graph is
-tested against; ``numpy`` assembles pool arrays on the host each step
-(portability fallback). See `serve.paged_decode`.
+Paged decode runs in one of two modes (``decode_mode``): ``fused``
+(default) executes the whole step as a single jitted, device-resident
+graph of any width — two host/device crossings per step, independent of
+depth; ``eager`` is the per-layer reference path the fused graph is
+tested against. See `serve.paged_decode`.
 
 Speculative multi-token decode (``speculate=k`` on the engine or per
 `Request`): a draft proposer (`serve.speculative`) guesses k-1 tokens per
@@ -106,8 +105,7 @@ class ServeEngine:
 
     def __init__(self, cfg: ModelConfig, params=None, seed: int = 0,
                  kv_pool: Optional[PagedKVPool] = None,
-                 device_gather: bool = True,
-                 decode_mode: Optional[str] = None,
+                 decode_mode: str = "fused",
                  knee_cache=None, speculate: int = 0, draft="ngram",
                  mesh=None):
         self.cfg = cfg
@@ -115,8 +113,6 @@ class ServeEngine:
         self.params = params if params is not None else \
             self.model.init(jax.random.PRNGKey(seed))
         self.kv_pool = kv_pool
-        if decode_mode is None:
-            decode_mode = "fused" if device_gather else "numpy"
         if decode_mode not in MODES:
             raise ValueError(f"decode_mode {decode_mode!r} not in {MODES}")
         self.decode_mode = decode_mode
@@ -124,8 +120,8 @@ class ServeEngine:
         # host mesh — on one device that collapses to plan=None, the exact
         # pre-mesh stack; a multi-device mesh shards decode rows over
         # "data" and attention/MLP heads over "model". Only the fused
-        # decode graph runs under shard_map (eager/numpy are the
-        # single-device references).
+        # decode graph runs under shard_map (eager is the single-device
+        # reference).
         if mesh is None and decode_mode == "fused":
             from repro.launch.mesh import make_host_mesh
             mesh = make_host_mesh()
@@ -163,8 +159,8 @@ class ServeEngine:
 
     def _check_spec_width(self, k: int):
         """Validate a k-token verify-graph width against the engine setup:
-        k > 1 requires the fused paged path — eager/numpy stay the 1-token
-        references — and k <= page_tokens (one verify step may cross at
+        k > 1 requires the fused paged path — eager stays the 1-token
+        reference — and k <= page_tokens (one verify step may cross at
         most one page boundary)."""
         if k <= 1:
             return
@@ -232,6 +228,7 @@ class ServeEngine:
 
     def _fused_step_fn(self, slots: int, greedy: bool, temperature: float,
                        k: int = 1, drafts: bool = True):
+        drafts = drafts and k > 1      # one token carries no draft
         key = (slots, greedy, float(temperature), k, drafts)
         fn = self._fused_cache.get(key)
         if fn is None:
@@ -241,19 +238,21 @@ class ServeEngine:
             self._fused_cache[key] = fn
         return fn
 
-    def _spec_step(self, state: PagedKVState, step_fn, k: int, rows, key):
-        """One speculative verify step over the current batch rows.
+    def _fused_step(self, state: PagedKVState, step_fn, k: int, rows, key):
+        """One fused step, ``k`` tokens wide, over the current batch rows:
+        plain decode (k = 1), speculative verify and prompt chunks alike.
 
         ``rows``: per batch row, ``None`` (dead/padded) or a dict with
-        ``seq`` (pool id), ``history`` (int32 array: true prompt + emitted
-        tokens, whose last entry is the token this step feeds), ``pos``
+        ``seq`` (pool id), ``last`` (the token this step feeds), ``pos``
         (absolute position of that token), ``eff_k`` (the request's
         per-step token budget), ``limit`` (tokens still allowed before
-        max_new, >= 1), ``eos`` (stop token or None) and ``stats``
-        (`SpecStats`). Proposes drafts, runs the widened fused step, and
-        advances the state by exactly the per-row kept counts — the
-        accepted prefix + bonus token, clamped by limit/eos; everything
-        else rolls back. Returns the per-row kept-token lists.
+        max_new, >= 1), ``eos`` (stop token or None), ``stats``
+        (`SpecStats`) and, for a row that proposes drafts (``eff_k >
+        1``), ``history`` (int32 array: true prompt + emitted tokens).
+        Proposes drafts, runs the fused step, and advances the state by
+        exactly the per-row kept counts — the accepted prefix + bonus
+        token, clamped by limit/eos; everything else rolls back. Returns
+        the per-row kept-token lists.
 
         A row may instead carry a prefill CHUNK (``{"seq", "pos",
         "chunk", "final"}``): up to k TRUE prompt tokens fed through the
@@ -289,11 +288,11 @@ class ServeEngine:
                 if m < k:               # pad: repeat the last true token
                     toks[i, m:] = chunk[-1]
                 continue
-            hist = r["history"]
-            toks[i, 0] = hist[-1]
+            toks[i, 0] = r["last"]
             n_d = min(r["eff_k"], k) - 1
             if n_d > 0:
-                drafts = np.asarray(self.draft.propose(hist, n_d), np.int32)
+                drafts = np.asarray(self.draft.propose(r["history"], n_d),
+                                    np.int32)
                 proposed[i] = len(drafts)
                 toks[i, 1:1 + len(drafts)] = drafts
             if proposed[i] < k - 1:     # pad: repeat the last filled token
@@ -408,9 +407,9 @@ class ServeEngine:
             step_fn = self._fused_step_fn(state.slots, greedy, temperature) \
                 if fused else None
             step_seqs = seq_ids + [-1] * (n_rows - b) if paged else None
-            if fused and n_rows > b:    # device-side pad: no extra upload
-                tok = jnp.concatenate(
-                    [tok, jnp.zeros(n_rows - b, jnp.int32)])
+            if fused:                   # mesh plan: pad rows feed token 0
+                tok = np.zeros(n_rows, np.int32)
+                tok[:b] = [o[0] for o in outs]
             for step in range(max_new - 1):
                 pos = plen + step
                 if paged:
@@ -418,11 +417,10 @@ class ServeEngine:
                              self.kv_pool.stats["slow_hits"])
                     g0 = state.gather_s
                     if fused:
-                        # steady state: one int32 control upload, one
-                        # sampled-token download — `tok` never leaves the
-                        # device
+                        # one int32 control upload (the tokens ride in
+                        # it), one sampled-token download
                         key, sub = jax.random.split(key)
-                        tok_host, tok = state.run_fused(
+                        tok = tok_host = state.run_fused(
                             step_fn, self.params, tok, step_seqs, pos, sub)
                     else:
                         logits = paged_decode_step(self.model, self.params,
@@ -501,7 +499,8 @@ class ServeEngine:
                 if done[i]:
                     rows.append(None)
                     continue
-                rows.append({"seq": seq_ids[i], "history": hist[i],
+                rows.append({"seq": seq_ids[i], "last": outs[i][-1],
+                             "history": hist[i],
                              "pos": plen + len(outs[i]) - 1,
                              "eff_k": eff_ks[i],
                              "limit": r.max_new_tokens - len(outs[i]),
@@ -512,7 +511,7 @@ class ServeEngine:
                      self.kv_pool.stats["slow_hits"])
             g0 = state.gather_s
             key, sub = jax.random.split(key)
-            kept = self._spec_step(state, step_fn, spec_k, rows, sub)
+            kept = self._fused_step(state, step_fn, spec_k, rows, sub)
             self.stats["decode_steps"] += 1
             if observe is not None:
                 observe(state.gather_s - g0,
@@ -686,7 +685,7 @@ class ServeSession:
         fused = engine.decode_mode == "fused"
         # chunked prefill streams prompt suffixes through the widened
         # fused verify graph in page-sized chunks riding the decode batch
-        # (None -> on for the fused mode); eager/numpy keep the monolithic
+        # (None -> on for the fused mode); eager keeps the monolithic
         # reference prefill
         if chunked_prefill and not fused:
             raise ValueError(
@@ -745,12 +744,7 @@ class ServeSession:
         self._recs: dict[int, _SessionRec] = {}
         self._key = jax.random.PRNGKey(seed)
         self._observe = getattr(self.pool.policy, "observe", None)
-        self._fused = engine.decode_mode == "fused"
-        self._step_fn = engine._fused_step_fn(self.state.slots, greedy,
-                                              temperature, k=k) \
-            if self._fused else None
-        self._tok_dev = None      # device-resident (max_active,) last tokens
-        self._rows_dirty = True   # host-known token entered/left a row
+        self._fused = fused
         self.steps = 0
         self.peak_live_pages = 0
         # SLO-aware preemption: when the admission round leaves a
@@ -886,7 +880,6 @@ class ServeSession:
             self.state.free_seq(act.seq)
             self._rows[rec.row] = None
             self.sched.retire(req)
-            self._rows_dirty = True
         rec.status = "cancelled"
         rec.active = None
         rec.result = np.array(outs[:req.max_new_tokens], np.int64)
@@ -989,7 +982,6 @@ class ServeSession:
         rec.row = -1
         rec.status = "preempted"
         self.sched.preempt(rec.req)
-        self._rows_dirty = True
         self.preemptions += 1
         if rec.metrics is not None:
             rec.metrics.on_preempt()
@@ -1033,7 +1025,6 @@ class ServeSession:
         self._rows[row_i] = act
         rec.row = row_i
         rec.status = "active"
-        self._rows_dirty = True
         self.resumes += 1
         if rec.metrics is not None:
             rec.metrics.on_resume()
@@ -1186,7 +1177,6 @@ class ServeSession:
                     self.pages_needed_total += self.sched.adopt_cap(req)
                     self._rows[row_i] = act
                     rec.active, rec.row, rec.status = act, row_i, "active"
-                    self._rows_dirty = True
                     if rec.metrics is not None:
                         rec.metrics.on_admit()
                     continue
@@ -1230,7 +1220,6 @@ class ServeSession:
                 act.outs.append(tok)
                 self._rows[row_i] = act
                 rec.active, rec.row, rec.status = act, row_i, "active"
-                self._rows_dirty = True
                 if rec.metrics is not None:
                     rec.metrics.on_admit()
                     rec.metrics.on_tokens(1)
@@ -1272,29 +1261,18 @@ class ServeSession:
             t = pool.page_tokens
             chunk_rows: dict[int, tuple[int, bool]] = {}   # row -> (m, final)
             wide = any(a is not None and a.prefilling for a in rows)
-            spec = self.spec_k > 1 or wide
-            n_rows = len(rows)      # mesh plan: max_active padded to dp blocks
-            if not spec:       # the spec branch derives these from srows
-                pos = np.zeros(n_rows, np.int32)
-                seq_ids = [-1] * n_rows
-                for i, act in enumerate(rows):
-                    if act is None:
-                        continue
-                    pos[i] = act.pos
-                    seq_ids[i] = act.seq
             t0 = time.perf_counter()
             hits0 = (pool.stats["fast_hits"], pool.stats["slow_hits"])
             g0 = state.gather_s
-            if spec:
-                # speculative verify step: k rows per live request, mixed
-                # freely with eff_k=1 (plain) rows and prefill chunk rows;
-                # tokens ride in the control block, so no device-token
-                # feedback is needed
+            if self._fused:
+                # one fused step k tokens wide (k = 1: plain decode): up
+                # to k rows per live request, mixed freely with eff_k=1
+                # (plain) rows and prefill chunk rows; the tokens ride in
+                # the control block
                 k = max(self.spec_k, t) if wide else self.spec_k
                 step_fn = eng._fused_step_fn(state.slots, self.greedy,
                                              self.temperature, k=k,
-                                             drafts=self.spec_k > 1) \
-                    if wide else self._step_fn
+                                             drafts=self.spec_k > 1)
                 budget = self.prefill_budget
                 srows: list[Optional[dict]] = []
                 for act in rows:
@@ -1316,40 +1294,25 @@ class ServeSession:
                                       "chunk": act.pending[:m],
                                       "final": final})
                         continue
-                    srows.append({
-                        "seq": act.seq,
-                        "history": np.concatenate(
+                    row = {"seq": act.seq, "last": act.outs[-1],
+                           "pos": act.pos, "eff_k": act.eff_k,
+                           "limit": act.req.max_new_tokens - len(act.outs),
+                           "eos": act.req.eos_token, "stats": act.stats}
+                    if act.eff_k > 1:   # only a drafting row reads history
+                        row["history"] = np.concatenate(
                             [np.asarray(act.req.prompt, np.int32),
-                             np.asarray(act.outs, np.int32)]),
-                        "pos": act.pos, "eff_k": act.eff_k,
-                        "limit": act.req.max_new_tokens - len(act.outs),
-                        "eos": act.req.eos_token, "stats": act.stats})
+                             np.asarray(act.outs, np.int32)])
+                    srows.append(row)
                 self._key, sub = jax.random.split(self._key)
-                kept = eng._spec_step(state, step_fn, k, srows, sub)
-                if wide:
-                    # the wide graph did not refresh the 1-token device
-                    # feedback vector — rebuild it on the next plain step
-                    self._rows_dirty = True
-                    self._tok_dev = None
-            elif self._fused:
-                tok_in = self._tok_dev
-                if self._rows_dirty or tok_in is None:
-                    # an admission (or a cancel) changed the row layout —
-                    # rebuild the token vector once (run_fused counts the
-                    # upload); steady-state steps feed the previous step's
-                    # device tokens back
-                    tok_in = np.zeros(n_rows, np.int32)
-                    for i, act in enumerate(rows):
-                        if act is not None:
-                            tok_in[i] = act.outs[-1]
-                    self._rows_dirty = False
-                self._key, sub = jax.random.split(self._key)
-                toks, self._tok_dev = state.run_fused(
-                    self._step_fn, eng.params, tok_in, seq_ids, pos, sub)
+                kept = eng._fused_step(state, step_fn, k, srows, sub)
             else:
+                n_rows = len(rows)
+                pos = np.zeros(n_rows, np.int32)
+                seq_ids = [-1] * n_rows
                 tokens = np.zeros(n_rows, np.int32)
                 for i, act in enumerate(rows):
                     if act is not None:
+                        pos[i], seq_ids[i] = act.pos, act.seq
                         tokens[i] = act.outs[-1]
                 logits = paged_decode_step(eng.model, eng.params, tokens,
                                            state, seq_ids, pos)
@@ -1358,6 +1321,12 @@ class ServeSession:
                                       self.temperature, sub)
                 with tracing.span("serve.device_wait"):
                     toks = np.asarray(sampled)
+                kept = [None] * n_rows
+                for i, act in enumerate(rows):
+                    if act is not None:
+                        kept[i] = [int(toks[i])]
+                        act.stats.steps += 1
+                        act.stats.tokens += 1
             dt = time.perf_counter() - t0
             eng.stats["decode_s"] += dt
             eng.stats["decode_steps"] += 1
@@ -1398,16 +1367,10 @@ class ServeSession:
                             self._finish(rec)
                         events.append(StreamEvent(act.req, [tok], done=done))
                         continue
-                    if spec:
-                        if kept[i] is None:   # over-budget prefill row idled
-                            continue
-                        new = [int(x) for x in kept[i]]
-                        act.outs.extend(new)
-                    else:
-                        new = [int(toks[i])]
-                        act.outs.append(new[0])
-                        act.stats.steps += 1
-                        act.stats.tokens += 1
+                    if kept[i] is None:   # over-budget prefill row idled
+                        continue
+                    new = [int(x) for x in kept[i]]
+                    act.outs.extend(new)
                     fed += len(new)
                     eng.stats["tokens"] += len(new)
                     if rec.metrics is not None:
@@ -1427,7 +1390,6 @@ class ServeSession:
                     if self.prefix_index is not None else None
                 pool.check_invariants(pins=pins)
                 state.check_invariants()
-                if state._device is not None:
-                    state._device.check_invariants()
+                state._device.check_invariants()
             self.peak_live_pages = max(self.peak_live_pages, pool.live_pages)
             return events

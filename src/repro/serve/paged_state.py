@@ -94,39 +94,49 @@ def state_kind(mixer: str):
 # Static layout: layer stack -> substrate map + control columns + page math
 # ---------------------------------------------------------------------------
 class ControlCols:
-    """Column offsets into the per-step int32 control block for one
-    (slots, k) shape. Pure-ATTN stacks keep the exact legacy layout; a
-    stack with recurrent or ring layers appends columns at the end:
+    """Column offsets into the per-step int32 control block, the one
+    definition for every width k:
 
-    ``rec``        this row's shard-local recurrent slot (has_rec)
+    ``[table | tail | spill | row | pos | len | k tokens | rec, fresh |
+    base | keep_fixed, keep_cap]``
+
+    ``table``      ``slots`` shard-local page slots, the tail slot (and,
+                   k > 1, the spill slot) behind the row's pages
+    ``tail``       the slot the step's first K/V row scatters into
+    ``spill``      k > 1: the slot rows past the page boundary scatter
+                   into; k = 1: the row's trash slot (nothing crosses)
+    ``row``        the tail slot's fill level (row of the first token)
+    ``pos``        absolute position of the first token (later tokens
+                   shift by +1 each inside the graph)
+    ``len``        KV length the first token attends, itself included
+    ``tok``        the k input tokens
+    ``rec``        this row's shard-local recurrent slot (recurrent
+                   layers only)
     ``fresh``      1 at a row's first step: its recurrent state starts
-                   from zero, not from the slot (has_rec)
+                   from zero, not from the slot (recurrent layers only)
     ``base``       dropped-ring-page count: table position n holds the
-                   logical page ``base + n`` (has_ring)
-    ``keep_fixed`` k > 1 only: fixed token-keep count for chunked
-                   prefill rows (-1 for verify rows, whose keep comes
-                   from the in-graph accept rule)
-    ``keep_cap``   k > 1 only: cap on accepted drafts (the row's real
-                   proposal count; pad drafts must not advance state)
+                   logical page ``base + n`` (ring layers only)
+    ``keep_fixed`` fixed token-keep count for chunked prefill rows (-1
+                   for verify rows, whose keep comes from the in-graph
+                   accept rule; recurrent layers only)
+    ``keep_cap``   cap on accepted drafts (the row's real proposal count;
+                   pad drafts must not advance state)
     """
 
-    def __init__(self, layout: "StateLayout", slots: int, k: int):
+    def __init__(self, slots: int, k: int = 1, rec: bool = False,
+                 ring: bool = False):
         s = slots
-        if k == 1:
-            self.tail, self.row, self.pos, self.len = s, s + 1, s + 2, s + 3
-            w = s + 4
-        else:
-            self.tail, self.spill = s, s + 1
-            self.row, self.pos, self.len = s + 2, s + 3, s + 4
-            self.tok = s + 5
-            w = s + 5 + k
-        if layout.has_rec:
+        self.tail, self.spill = s, s + 1
+        self.row, self.pos, self.len = s + 2, s + 3, s + 4
+        self.tok = s + 5
+        w = s + 5 + k
+        if rec:
             self.rec, self.fresh = w, w + 1
             w += 2
-        if layout.has_ring:
+        if ring:
             self.base = w
             w += 1
-        if layout.has_rec and k > 1:
+        if rec:
             self.keep_fixed, self.keep_cap = w, w + 1
             w += 2
         self.width = w
@@ -196,7 +206,7 @@ class StateLayout:
 
     # -- control block -------------------------------------------------------
     def cols(self, slots: int, k: int = 1) -> ControlCols:
-        return ControlCols(self, slots, k)
+        return ControlCols(slots, k, rec=self.has_rec, ring=self.has_ring)
 
     # -- ring math -----------------------------------------------------------
     def ring_pages(self) -> int:

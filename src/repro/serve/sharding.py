@@ -144,9 +144,6 @@ class ServePlan:
         """The per-step int32 control block: rows over data."""
         return NamedSharding(self.mesh, P("data", None))
 
-    def token_sharding(self) -> NamedSharding:
-        return NamedSharding(self.mesh, P("data"))
-
     # -- params -------------------------------------------------------------
     def _param_spec(self, shape, logical) -> P:
         logical = tuple(logical)

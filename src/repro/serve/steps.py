@@ -3,7 +3,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -59,28 +58,6 @@ def make_paged_decode_step(model: Model, state, backend: str = "auto"):
         logits = paged_decode_step(model, params, tokens, state, seq_ids,
                                    pos, backend=backend)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
-    return decode_step
-
-
-def make_fused_decode_step(model: Model, state, backend: str = "auto",
-                           greedy: bool = True, temperature: float = 1.0):
-    """Step-function wrapper over the fused jitted decode graph
-    (`paged_decode.build_fused_step`): one call = one token for the whole
-    batch, with the host side reduced to the state's begin/end
-    bookkeeping (`PagedKVState.run_fused` owns the transfer accounting).
-    Unlike `make_paged_decode_step` it returns only the sampled tokens —
-    logits never leave the device. Passing host `tokens` costs one extra
-    upload per call; pass the previous call's device tokens (second
-    return value) to stay at the steady-state 2 crossings per token."""
-    from repro.serve.paged_decode import build_fused_step
-
-    fused = build_fused_step(model, state.slots, backend=backend,
-                             greedy=greedy, temperature=temperature)
-
-    def decode_step(params, tokens, seq_ids, pos, key=None):
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        return state.run_fused(fused, params, tokens, seq_ids, pos, key)
     return decode_step
 
 

@@ -51,16 +51,6 @@ def test_continuous_matches_per_request_static_greedy(cfg, ref):
     assert pool.stats["freed"] > 0
 
 
-def test_numpy_gather_fallback_matches(cfg, ref):
-    params, prompts, news, expected = ref
-    pool = PagedKVPool(page_tokens=4)
-    eng = ServeEngine(cfg, params=params, kv_pool=pool, device_gather=False)
-    outs = eng.serve(_requests(prompts, news), max_active=2)
-    for want, got in zip(expected, outs):
-        np.testing.assert_array_equal(want, got)
-    assert len(pool.pages) == 0
-
-
 def test_eos_token_retires_early(cfg, ref):
     params, prompts, _, _ = ref
     pool = PagedKVPool(page_tokens=4)
@@ -157,14 +147,10 @@ def test_gather_slot_overflow_raises_value_error(cfg, rng):
     """More pages than the page table holds must raise (not a stripped-out
     assert): a `python -O` server must not silently corrupt the table."""
     pool = PagedKVPool(page_tokens=4)
-    state = PagedKVState(pool, capacity=8, num_layers=1, hkv=2, hd=8,
-                         mode="numpy")
+    state = PagedKVState(pool, capacity=8, num_layers=1, hkv=2, hd=8)
     kv = rng.standard_normal((4 * (state.slots + 1), 2, 8)) \
         .astype(np.float32)
     state.write_prefill(0, 0, kv, kv.copy())
-    with pytest.raises(ValueError, match="sequence 0"):
-        state.gather(0, [0])
-    # the device-resident step protocol enforces the same bound
     with pytest.raises(ValueError, match="sequence 0"):
         state.begin_step([0], np.zeros(1, np.int32))
 

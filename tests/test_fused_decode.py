@@ -2,7 +2,7 @@
 device-resident graph must reproduce the per-layer eager paged path
 token-for-token (static + continuous, dead rows, int8 slow tier, mid-run
 LRU demotion), while crossing the host/device boundary exactly twice per
-steady-state token — independent of the number of layers."""
+step — independent of the number of layers — at every width."""
 import dataclasses
 
 import jax
@@ -101,13 +101,58 @@ def test_fused_matches_eager_under_lru_demotion(cfg, params):
 
 
 # ---------------------------------------------------------------------------
+# One builder for every width
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("drafts", [False, True])
+def test_width_one_and_width_four_steps_sample_alike(cfg, params, drafts):
+    """Plain rows (the last token, then padding: no drafts) through a
+    4-wide step sample the greedy token the 1-wide step samples, step
+    after step across page boundaries (the 4-wide step's rows spill),
+    beside a dead row; the 1-wide verdict's count column is 0."""
+    from repro.serve.paged_decode import (PagedKVState, build_fused_step,
+                                          extract_prefill_pages)
+
+    eng = _engine(cfg, params, "fused")
+    prompts = np.stack([r.prompt for r in _reqs(cfg, plen=6)])
+    logits, caches = jax.jit(eng.model.forward_prefill)(
+        eng.params, {"tokens": jnp.asarray(prompts)})
+    first = np.append(np.asarray(jnp.argmax(logits, -1), np.int32), 0)
+    seqs = [0, 1, -1]
+
+    def run(k):
+        state = PagedKVState(PagedKVPool(page_tokens=4), 32, cfg.num_layers,
+                             cfg.num_kv_heads, cfg.head_dim, batch_hint=3,
+                             tail_slots=2)
+        extract_prefill_pages(eng.model, caches, state, [0, 1])
+        step = build_fused_step(eng.model, state.slots, k=k, drafts=drafts)
+        tok, outs = first, []
+        for j in range(6):
+            verdict = state.run_spec(step, eng.params,
+                                     np.repeat(tok[:, None], k, axis=1),
+                                     seqs, np.full(3, 6 + j, np.int32),
+                                     jax.random.PRNGKey(j))
+            assert verdict.shape == (3, k + 1)
+            if k == 1:
+                assert not verdict[:, 1].any()
+            state.end_step(seqs, [1, 1, 0])
+            tok = verdict[:, 0]
+            outs.append(tok[:2])
+        for seq in (0, 1):
+            state.free_seq(seq)
+        return np.stack(outs)
+
+    np.testing.assert_array_equal(run(1), run(4))
+
+
+# ---------------------------------------------------------------------------
 # Host <-> device transfer accounting
 # ---------------------------------------------------------------------------
 def test_fused_steady_state_two_transfers_per_token(cfg):
-    """Steady state (no page fills, mirror synced): one int32 control
-    upload + one sampled-token download per token, with zero device-pool
-    scatters/readbacks — at every depth. The eager reference pays ~2
-    crossings per *layer* per token instead."""
+    """Every step, the first included: one int32 control upload (the
+    tokens ride in it) + one verdict download; in steady state (no page
+    fills, mirror synced) zero device-pool scatters/readbacks besides —
+    at every depth. The eager reference pays ~2 crossings per *layer*
+    per token instead."""
     from repro.serve.paged_decode import (PagedKVState, build_fused_step,
                                           extract_prefill_pages)
 
@@ -126,17 +171,25 @@ def test_fused_steady_state_two_transfers_per_token(cfg):
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # first step syncs prefill pages into the mirror
-        _, tok = state.run_fused(fused, eng.params, tok, [0], 20, key)
+        tok = state.run_fused(fused, eng.params, tok, [0], 20, key)
+        assert (state.h2d, state.d2h) == (1, 1)
         writes0 = state._device.writes
         h0, d0 = state.transfer_counts()
         for s in range(3):                 # tail rows 5..7 of 16: no fill
-            _, tok = state.run_fused(fused, eng.params, tok, [0], 21 + s,
-                                     key)
+            tok = state.run_fused(fused, eng.params, tok, [0], 21 + s, key)
         h1, d1 = state.transfer_counts()
         assert state._device.writes == writes0     # no scatters, no syncs
         per_depth[num_layers] = (h1 - h0, d1 - d0)
         assert per_depth[num_layers] == (3, 3)     # 2 transfers per token
+        assert (state.h2d, state.d2h) == (4, 4)
     assert per_depth[2] == per_depth[4]            # independent of depth
+
+
+def test_numpy_decode_mode_is_refused(cfg, params):
+    """Two decode modes remain: the fused step and the eager reference."""
+    with pytest.raises(ValueError, match="numpy"):
+        ServeEngine(cfg, params=params, kv_pool=PagedKVPool(page_tokens=4),
+                    decode_mode="numpy")
 
 
 def test_eager_transfers_scale_with_depth_fused_do_not(cfg, params):

@@ -201,7 +201,7 @@ def test_rec_store_counters_constant_per_step(cfgs, params):
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     per_step = []
     for s in range(40):
-        _, tok = state.run_fused(fused, eng.params, tok, [0], 8 + s, key)
+        tok = state.run_fused(fused, eng.params, tok, [0], 8 + s, key)
         per_step.append((state._rec.writes - w0, state._rec.reads - r0))
     # no host crossings at any position: early and late steps identical
     assert per_step[0] == per_step[-1] == (0, 0)
@@ -226,7 +226,7 @@ def test_ring_pages_bounded_o_window(cfgs, params):
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     counts = []
     for s in range(40):
-        _, tok = state.run_fused(fused, eng.params, tok, [0], 32 + s, key)
+        tok = state.run_fused(fused, eng.params, tok, [0], 32 + s, key)
         counts.append(len(eng.kv_pool.seq_pages(0, 0)))
     assert max(counts) <= layout.ring_pages()    # O(window), not O(len)
     assert counts[-1] == counts[-2]              # steady state: recycled
@@ -236,8 +236,8 @@ def test_ring_pages_bounded_o_window(cfgs, params):
 # Transfer accounting: 2 host<->device crossings per steady-state token
 # ---------------------------------------------------------------------------
 def test_hybrid_two_transfers_per_token(cfgs, params):
-    """Pure SSM steady state: one control upload + one token download
-    per token; the recurrent state never crosses."""
+    """Pure SSM: one control upload + one verdict download per token,
+    the first step included; the recurrent state never crosses."""
     cfg = cfgs["mamba2-780m"]
     eng = ServeEngine(cfg, params=params["mamba2-780m"],
                       kv_pool=PagedKVPool(page_tokens=16))
@@ -251,12 +251,14 @@ def test_hybrid_two_transfers_per_token(cfgs, params):
     fused = build_fused_step(eng.model, state.slots, layout=layout)
     key = jax.random.PRNGKey(0)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
-    _, tok = state.run_fused(fused, eng.params, tok, [0], 8, key)
+    tok = state.run_fused(fused, eng.params, tok, [0], 8, key)
+    assert (state.h2d, state.d2h) == (1, 1)
     h0, d0 = state.transfer_counts()
     for s in range(3):
-        _, tok = state.run_fused(fused, eng.params, tok, [0], 9 + s, key)
+        tok = state.run_fused(fused, eng.params, tok, [0], 9 + s, key)
     h1, d1 = state.transfer_counts()
     assert (h1 - h0, d1 - d0) == (3, 3)
+    assert (state.h2d, state.d2h) == (4, 4)
 
 
 # ---------------------------------------------------------------------------
@@ -289,10 +291,9 @@ def test_swap_out_in_bit_identical(cfgs, params, arch):
                 out_b = state.swap_out(0)
                 in_b = state.swap_in(0)
                 assert out_b > 0 and in_b > 0      # state actually moved
-                tok = jnp.asarray([outs[-1]], jnp.int32)   # re-upload
-            _, tok = state.run_fused(fused, eng.params, tok, [0], 10 + s,
+            tok = state.run_fused(fused, eng.params, tok, [0], 10 + s,
                                      key)
-            outs.append(int(np.asarray(tok)[0]))
+            outs.append(int(tok[0]))
         for seq in [0]:
             state.free_seq(seq)
         return outs

@@ -40,48 +40,38 @@ def ring_params(ring_cfg):
     return ServeEngine(ring_cfg).params
 
 
-def len_column(state, k):
-    """The control block's KV-length column."""
-    s = state.slots
-    if state.layout is not None:
-        return state.layout.cols(s, k).len
-    return s + 3 if k == 1 else s + 4
-
-
 def reference_control(state, control, seq_ids, k):
     """``control`` with its page-derived columns rebuilt from the pool
     and the mirror: each live row's page groups zipped from the pool's
-    per-layer page lists, their slots, the tail (and spill) slot behind
-    them, and the KV length; dead rows the trash slot and length 1."""
+    per-layer page lists, their slots, the tail (and, k > 1, spill) slot
+    behind them, and the KV length; dead rows the trash slot and length
+    1. The spill column of a 1-wide step holds the row's trash slot."""
     ref = control.copy()
     pool, dev, t, s = state.pool, state._device, state.pool.page_tokens, \
         state.slots
-    c_tail = s if state.layout is None else state.layout.cols(s, k).tail
-    c_len = len_column(state, k)
+    cc = state.cols(k)
     b = len(seq_ids)
-    shards = dev.shards if dev is not None else 1
     ref[:, :s] = 0
     for i, seq in enumerate(seq_ids):
-        sh = i * shards // b
+        sh = i * dev.shards // b
+        trash = dev.local_slot(state._trash[sh])
+        ref[i, cc.spill] = trash
         if seq < 0:
-            if dev is not None:
-                ref[i, c_tail] = dev.local_slot(state._trash[sh])
-                if k > 1:
-                    ref[i, s + 1] = ref[i, c_tail]
-            ref[i, c_len] = 1
+            ref[i, cc.tail] = trash
+            ref[i, cc.len] = 1
             continue
         per_layer = [pool.seq_pages(seq, l) for l in range(state.num_layers)]
         groups = list(zip(*per_layer)) if per_layer else []
         n = len(groups)
-        if dev is not None and state.num_layers:
+        if state.num_layers:
             for j, g in enumerate(groups):
                 ref[i, j] = dev.local_slot(dev.slot(g[0], sh))
-            ref[i, c_tail] = dev.local_slot(state._tail_slot[seq])
-            ref[i, n] = ref[i, c_tail]
+            ref[i, cc.tail] = dev.local_slot(state._tail_slot[seq])
+            ref[i, n] = ref[i, cc.tail]
             if k > 1:
-                ref[i, s + 1] = dev.local_slot(state._spill_slot[seq])
-                ref[i, n + 1] = ref[i, s + 1]
-        ref[i, c_len] = n * t + state.tail_len.get(seq, 0) + 1
+                ref[i, cc.spill] = dev.local_slot(state._spill_slot[seq])
+                ref[i, n + 1] = ref[i, cc.spill]
+        ref[i, cc.len] = n * t + state.tail_len.get(seq, 0) + 1
     return ref
 
 
@@ -132,7 +122,7 @@ def check_every_step(state):
         c = tracing.spans(name="serve.begin_step")[-1].counts
         counts.append((c["rows"], c["rebuilt"]))
         s, t = state.slots, state.pool.page_tokens
-        lengths = ref[:, len_column(state, k)]
+        lengths = ref[:, state.cols(k).len]
         assert c["streamed"] == live_pages(lengths, k, t, s).sum()
         assert c["table"] == len(seq_ids) * s
         return control

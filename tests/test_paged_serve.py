@@ -63,21 +63,6 @@ def test_paged_decode_with_slow_tier_generates_and_hits():
     assert pool.stats["slow_hits"] > 0 and pool.stats["fast_hits"] == 0
 
 
-def test_device_and_numpy_gather_agree():
-    """The device-resident gather (index updates into preallocated jax
-    arrays) and the numpy fallback (per-step pool stacking) feed the
-    kernel identical content."""
-    cfg = smoke_config("starcoder2-7b")
-    dev = ServeEngine(cfg, kv_pool=PagedKVPool(page_tokens=4))
-    outs_dev = dev.generate(_reqs(cfg))
-    host = ServeEngine(cfg, params=dev.params,
-                       kv_pool=PagedKVPool(page_tokens=4),
-                       device_gather=False)
-    outs_host = host.generate(_reqs(cfg))
-    for a, b in zip(outs_dev, outs_host):
-        np.testing.assert_array_equal(a, b)
-
-
 def test_sibyl_placement_learns_from_serve_feedback():
     """The Sibyl DQN driven as the pool's placement policy receives
     deferred rewards from observed gather latency + slow-hit penalty and

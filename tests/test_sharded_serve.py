@@ -180,7 +180,8 @@ def test_transfers_per_token_mesh_independent(cfg, params):
 @needs8
 def test_sharded_steady_state_two_transfers_per_token(cfg):
     """The low-level steady-state idiom of test_fused_decode on a
-    tp-sharded mesh: once the mirror is synced, 3 tokens cost exactly
+    tp-sharded mesh: one control upload and one download a step, the
+    first included; once the mirror is synced, 3 tokens cost exactly
     (3, 3) transfers and zero pool scatters."""
     from repro.launch.mesh import make_serve_mesh
     from repro.serve.paged_decode import (PagedKVState, build_fused_step,
@@ -203,14 +204,16 @@ def test_sharded_steady_state_two_transfers_per_token(cfg):
     key = jax.random.PRNGKey(0)
     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    _, tok = state.run_fused(fused, eng.params, tok, [0], 20, key)
+    tok = state.run_fused(fused, eng.params, tok, [0], 20, key)
+    assert (state.h2d, state.d2h) == (1, 1)
     writes0 = state._device.writes
     h0, d0 = state.transfer_counts()
     for s in range(3):
-        _, tok = state.run_fused(fused, eng.params, tok, [0], 21 + s, key)
+        tok = state.run_fused(fused, eng.params, tok, [0], 21 + s, key)
     h1, d1 = state.transfer_counts()
     assert state._device.writes == writes0
     assert (h1 - h0, d1 - d0) == (3, 3)
+    assert (state.h2d, state.d2h) == (4, 4)
 
 
 @needs8

@@ -144,9 +144,7 @@ def test_fused_step_compiles_at_16_layers(one_chip, monkeypatch, k):
     control = sds((BATCH, lay.cols(SLOTS, k).width), "int32")
     key = sds((2,), "uint32")
     step = build_fused_step(model, SLOTS, k=k, layout=lay)
-    inputs = (params, args, sds((BATCH,), "int32"), control, key) \
-        if k == 1 else (params, args, control, key)
-    compiled = step.lower(*inputs).compile()
+    compiled = step.lower(params, args, control, key).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -191,9 +189,7 @@ def test_nemotron_stage_step_fits_one_chip(one_chip, monkeypatch, k):
     control = sds((rows, lay.cols(slots, k).width), "int32")
     key = sds((2,), "uint32")
     step = build_fused_step(model, slots, k=k, layout=lay, drafts=False)
-    inputs = (params, arrays, sds((rows,), "int32"), control, key) \
-        if k == 1 else (params, arrays, control, key)
-    compiled = step.lower(*inputs).compile()
+    compiled = step.lower(params, arrays, control, key).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, \
